@@ -34,6 +34,24 @@ ARTIFACT = pathlib.Path(__file__).resolve().parent.parent / "BENCH_fit.json"
 BENCH_THREADS = 4
 
 
+def mega_grid_spaces() -> dict:
+    """The 10^5- and 10^6-candidate grids of the mega-grid recommend rows
+    (also driven by ``chip_smoke.py``)."""
+    from repro.core import ConfigSpace
+
+    knobs = dict(
+        batch_size=(16, 24, 32, 48, 64, 96, 128, 192, 256, 384),
+        num_workers=(0, 1, 2, 3, 4, 6, 8, 12, 16, 24),
+        block_kb=(4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048),
+        n_threads=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
+        prefetch_depth=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32))
+    return {
+        "1e5": ConfigSpace(**knobs),
+        "1e6": ConfigSpace(**knobs, prefetch_policy=(0, 1),
+                           lookahead_batches=(4, 8, 16, 32, 64)),
+    }
+
+
 def _cores() -> int:
     try:
         return len(os.sched_getaffinity(0))
@@ -251,11 +269,7 @@ def bench_fit(fast: bool, artifact_dir: Optional[pathlib.Path] = None) -> List[R
     grids = {
         # the paper's full §5.2 sweep: DEFAULT_SPACE, 1,800 candidates (~10^3)
         "paper_1800": ConfigSpace(),
-        "1e5": ConfigSpace(batch_size=(16, 24, 32, 48, 64, 96, 128, 192, 256, 384),
-                           num_workers=(0, 1, 2, 3, 4, 6, 8, 12, 16, 24),
-                           block_kb=(4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048),
-                           n_threads=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
-                           prefetch_depth=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32)),  # 10^5
+        "1e5": mega_grid_spaces()["1e5"],
     }
     if fast:
         grids.pop("1e5")
@@ -281,22 +295,7 @@ def bench_fit(fast: bool, artifact_dir: Optional[pathlib.Path] = None) -> List[R
     # scorer (Pallas kernel on TPU, jitted dense descent elsewhere) beats
     # the monolithic numpy/argpartition path >= 1.5x AND picks the same
     # top-k.  Fast mode measures the 10^5 grid; full runs the 10^6 grid.
-    mega_grids = {
-        "1e5": ConfigSpace(
-            batch_size=(16, 24, 32, 48, 64, 96, 128, 192, 256, 384),
-            num_workers=(0, 1, 2, 3, 4, 6, 8, 12, 16, 24),
-            block_kb=(4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048),
-            n_threads=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
-            prefetch_depth=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32)),  # 10^5
-        "1e6": ConfigSpace(
-            batch_size=(16, 24, 32, 48, 64, 96, 128, 192, 256, 384),
-            num_workers=(0, 1, 2, 3, 4, 6, 8, 12, 16, 24),
-            block_kb=(4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048),
-            n_threads=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
-            prefetch_depth=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
-            prefetch_policy=(0, 1),
-            lookahead_batches=(4, 8, 16, 32, 64)),  # 10^6
-    }
+    mega_grids = mega_grid_spaces()
     if fast:
         mega_grids.pop("1e6")
     else:

@@ -99,4 +99,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -165,12 +165,9 @@ def _packed_model(predictor) -> Optional[PackedEnsemble]:
 
 
 def _on_tpu() -> bool:
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax is a hard dep everywhere else
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _resolve_scorer(scorer: str, ens: Optional[PackedEnsemble], n: int) -> str:
@@ -209,9 +206,8 @@ def _score_grid_packed(
         for j, name in enumerate(names)
         if name not in KNOB_NAMES
     ]
-    interpret = pallas and not _on_tpu()
     if pallas:
-        from ..kernels.gbt_predict import gbt_predict_ensemble
+        from ..kernels.ops import gbt_predict_op
     scores = np.empty(n, np.float32)
     buffers: Dict[int, np.ndarray] = {}
     lo = 0
@@ -229,7 +225,7 @@ def _score_grid_packed(
             if rows < padded:
                 buf[rows:, j] = 0.0
         if pallas:
-            out = gbt_predict_ensemble(ens, buf, interpret=interpret)
+            out = gbt_predict_op(buf, ens)
         else:
             out = predict_ensemble(ens, buf)
         scores[lo : lo + rows] = np.asarray(out)[:rows]

@@ -1,4 +1,5 @@
-"""repro.kernels — Pallas TPU kernels (validated in interpret mode on CPU).
+"""repro.kernels — Pallas TPU kernels (compiled on a TPU; the CPU tests ask
+for interpret mode).
 
 flash_attention: dominant FLOP hot-spot of every transformer cell.
 rmsnorm:        fused memory-bound norm.
@@ -6,4 +7,4 @@ gbt_predict:    the paper's hot path — batched ensemble inference for
                 autotune sweeps, one-hot-matmul descent (gather-free).
 """
 
-from .ops import flash_attention_op, gbt_predict_op, rmsnorm_op  # noqa: F401
+from .ops import gbt_predict_op  # noqa: F401

@@ -1,50 +1,23 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Scoring a ``core.ensemble_base.PackedEnsemble`` with the Pallas kernel.
 
-On this CPU container the kernels run with ``interpret=True`` (the Pallas
-interpreter executes the kernel body in Python — exact semantics, no TPU).
-On TPU set ``REPRO_KERNEL_INTERPRET=0`` (or pass interpret=False) to compile
-the real Mosaic kernels.
+The kernel compiles for the TPU.  ``interpret=True`` runs the same kernel
+body in the Pallas interpreter instead (exact semantics, any backend); only
+a caller that asks for it gets it, as the CPU tests do.
 """
 
 from __future__ import annotations
 
-import os
-from typing import Optional
-
-import jax
 import jax.numpy as jnp
 
-from .flash_attention import flash_attention as _flash
 from .gbt_predict import gbt_predict as _gbt
-from .rmsnorm import rmsnorm as _rmsnorm
 
 
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
-def flash_attention_op(q, k, v, *, causal=True, window=None, prefix=None,
-                       scale=None, q_block=256, kv_block=256, interpret=None):
-    return _flash(q, k, v, causal=causal, window=window, prefix=prefix,
-                  scale=scale, q_block=q_block, kv_block=kv_block,
-                  interpret=_default_interpret() if interpret is None else interpret)
-
-
-def rmsnorm_op(x, scale, *, eps=1e-6, block_rows=256, interpret=None):
-    return _rmsnorm(x, scale, eps=eps, block_rows=block_rows,
-                    interpret=_default_interpret() if interpret is None else interpret)
-
-
-def gbt_predict_op(X, ensemble, *, row_block=256, interpret=None):
-    """ensemble: core.ensemble_base.PackedEnsemble."""
+def gbt_predict_op(X, ensemble, *, row_block=None, interpret=False):
+    """Score ``X`` [N, F] with a ``core.ensemble_base.PackedEnsemble``."""
     return _gbt(
         jnp.asarray(X, jnp.float32),
         ensemble.feature, ensemble.threshold, ensemble.left, ensemble.right,
         ensemble.value, max_depth=ensemble.max_depth,
         base_score=float(ensemble.base_score), scale=float(ensemble.scale),
-        row_block=row_block,
-        interpret=_default_interpret() if interpret is None else interpret,
+        row_block=row_block, interpret=interpret,
     )

@@ -74,4 +74,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from ..compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

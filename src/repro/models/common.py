@@ -346,14 +346,17 @@ def chunked_cross_entropy(x2d, unembed, labels, *, chunk: int = 4096,
     """x2d: [T, D] hidden; unembed: [D, V]; labels: [T] int32. Mean NLL.
 
     Scans token chunks so the [chunk, V] logits tensor never materializes for
-    all T at once; body is rematerialized in backward.
+    all T at once; body is rematerialized in backward.  Chunk ``j`` holds the
+    strided tokens ``j, j+n, j+2n, ...``: the token axis is split as
+    ``[c, n]`` so its (batch) sharding stays on the ``c`` axis inside the body,
+    and the scanned axis ``n`` is replicated, as ``lax.scan`` requires.
     """
     T, D = x2d.shape
     c = min(chunk, T)
     while T % c:  # largest divisor of T not exceeding the requested chunk
         c -= 1
     n = T // c
-    xs = (x2d.reshape(n, c, D), labels.reshape(n, c))
+    xs = (jnp.swapaxes(x2d.reshape(c, n, D), 0, 1), labels.reshape(c, n).T)
 
     @jax.checkpoint
     def body(tot, xs):

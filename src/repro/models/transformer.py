@@ -34,21 +34,6 @@ from .common import (
 )
 from .config import ModelConfig
 
-# shard_map moved out of jax.experimental, and its replication-check kwarg
-# was renamed check_rep -> check_vma, on independent version boundaries;
-# resolve both from what this jax actually exposes.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHMAP_KW = {
-    ("check_vma" if "check_vma" in _inspect.signature(_shard_map).parameters
-     else "check_rep"): False
-}
-
 
 # --------------------------------------------------------------------------
 # Shard context
@@ -261,9 +246,9 @@ def _moe_block_fn(cfg: ModelConfig, ctx: ShardCtx):
         body = shmap_fn if cfg.gated_mlp else (
             lambda x, r, wi, wo: shmap_fn(x, r, wi, None, wo)
         )
-        fn = _shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=x_spec, **_SHMAP_KW,
+            out_specs=x_spec, check_vma=False,
         )
         return fn(*args)
 

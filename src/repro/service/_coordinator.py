@@ -48,6 +48,22 @@ class FleetConfig(LoopConfig):
             raise ValueError(f"unknown executor kind {self.executor_kind!r}")
 
 
+def collector_env() -> dict:
+    """Environment of a spawned collector process.
+
+    An active fault plan rides along (``REPRO_FAULT_PLAN``), so collectors
+    inject from the same seeded schedule as the coordinator.  Collectors do
+    host I/O only and never import JAX; ``JAX_PLATFORMS=cpu`` makes that
+    explicit, so the coordinator, which refits with JAX, stays the only
+    process that can claim the accelerator (a chip serves one process)."""
+    env = dict(os.environ)
+    src = str(pathlib.Path(__file__).resolve().parents[2])
+    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
+                         if env.get("PYTHONPATH") else src)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 class _SubprocessCollector:
     """Default collector handle: a real ``--role collector`` child process."""
 
@@ -147,15 +163,9 @@ class FleetCoordinator(ContinuousTuningLoop):
         argv += ["--max-retries", str(self.cfg.max_retries),
                  "--quarantine-after",
                  str(self.cfg.quarantine_after or 0)]
-        # an active fault plan rides along in env (REPRO_FAULT_PLAN), so
-        # collectors inject from the same seeded schedule as the coordinator
-        env = dict(os.environ)
-        src = str(pathlib.Path(__file__).resolve().parents[2])
-        env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
-                             if env.get("PYTHONPATH") else src)
         log_path = (self.cfg.out_dir / "logs"
                     / f"collector_c{cycle:04d}_s{shard}_a{attempt}.log")
-        return _SubprocessCollector(argv, env, log_path)
+        return _SubprocessCollector(argv, collector_env(), log_path)
 
     def _lease(self, leases: Dict[int, _Lease], shard: int, cycle: int,
                attempt: int) -> None:

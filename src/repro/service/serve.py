@@ -67,6 +67,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..compile_cache import use_compile_cache
 from ..core.autotune import KNOB_NAMES, ConfigSpace, OnlineAutotuner, recommend
 from ..core.ensemble_base import ceil_pow2
 from ..core.features import TARGET_NAME
@@ -751,11 +752,13 @@ def _make_handler(service: RecommendationService):
 
 # ---------------------------------------------------------------- warm start
 
-def synthetic_observations(space: ConfigSpace, n_repeats: int = 2) -> List[dict]:
+def synthetic_observations(space: ConfigSpace, n_repeats: int = 2,
+                           seed: int = 0) -> List[dict]:
     """Deterministic knob-sweep observations (no storage I/O): workers and
     prefetch help with diminishing returns, larger batches amortize overhead.
     Enough cross-config signal for a real fit — the --smoke/--demo warm
-    path and the serve benchmark both start from this."""
+    path and the serve benchmark both start from this.  ``seed`` shifts the
+    small per-row jitter (seed 0 is the historical set)."""
     rows: List[dict] = []
     for rep in range(n_repeats):
         for i, cand in enumerate(space.candidates()):
@@ -764,7 +767,8 @@ def synthetic_observations(space: ConfigSpace, n_repeats: int = 2) -> List[dict]
             b = cand.get("batch_size", 64)
             thr = 80.0 * (1 + 0.9 * w ** 0.7) * (1 + 0.15 * (pf - 1))
             thr *= (b / 64.0) ** 0.2
-            thr *= 1 + 0.01 * ((i * 2654435761 + rep * 97) % 17 - 8) / 8.0
+            thr *= 1 + 0.01 * ((i * 2654435761 + rep * 97 + seed * 7919)
+                               % 17 - 8) / 8.0
             rows.append({**cand, "file_size_mb": 64.0, "n_samples": 1000.0,
                          TARGET_NAME: thr})
     return rows
@@ -917,4 +921,5 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.exit(main())

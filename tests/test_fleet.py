@@ -6,6 +6,8 @@ provenance in ``--status``."""
 import os
 import signal
 import socket
+import subprocess
+import sys
 import threading
 import time
 
@@ -302,6 +304,27 @@ def test_fleet_resume_between_cycles_matches_straight_run(tmp_path):
 
 
 # ------------------------------------------------------- real processes
+
+
+def test_collector_process_never_imports_jax(tmp_path):
+    """A collector process runs its shard with the real I/O executor and
+    never imports JAX; its environment pins JAX to the CPU besides, so the
+    coordinator stays the only process that can claim the accelerator."""
+    from repro.service._coordinator import collector_env
+
+    env = collector_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    argv = ["--role", "collector", "--campaign", "paper_concurrent", "--fast",
+            "--out-dir", str(tmp_path / "c"), "--cycle", "0", "--shard", "0/2",
+            "--seeds", "1000"]
+    script = ("import sys\n"
+              "from repro.service.fleet import main\n"
+              f"rc = main({argv!r})\n"
+              "assert 'jax' not in sys.modules, 'collector imported jax'\n"
+              "sys.exit(rc)\n")
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
 
 
 def test_fleet_kill9_subprocess_collector_recovers(tmp_path):

@@ -1,4 +1,5 @@
-"""Per-kernel allclose tests vs the pure-jnp oracles (interpret mode on CPU),
+"""Per-kernel allclose tests vs the pure-jnp oracles (each call asks for
+interpret mode, so they run on the CPU),
 with hypothesis sweeps over shapes and dtypes."""
 
 import jax
@@ -7,7 +8,9 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.ops import flash_attention_op, gbt_predict_op, rmsnorm_op
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ops import gbt_predict_op
+from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ref import (
     attention_reference,
     gbt_predict_reference,
@@ -34,7 +37,7 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 )
 def test_flash_attention_masks_dtypes(dtype, mask):
     q, k, v = _qkv(jax.random.PRNGKey(0), 2, 256, 8, 4, 64, dtype)
-    o = flash_attention_op(q, k, v, q_block=64, kv_block=64, **mask)
+    o = flash_attention(q, k, v, q_block=64, kv_block=64, interpret=True, **mask)
     r = attention_reference(q, k, v, **mask)
     err = float(jnp.max(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32))))
     assert err < TOL[dtype], (mask, err)
@@ -54,7 +57,8 @@ def test_flash_attention_shape_sweep(b, n_blocks, h_pow, g_pow, dh, causal):
     H = KV * 2 ** g_pow
     S = 64 * n_blocks
     q, k, v = _qkv(jax.random.PRNGKey(b), b, S, H, KV, dh, jnp.float32)
-    o = flash_attention_op(q, k, v, causal=causal, q_block=64, kv_block=64)
+    o = flash_attention(q, k, v, causal=causal, q_block=64, kv_block=64,
+                           interpret=True)
     r = attention_reference(q, k, v, causal=causal)
     assert o.shape == q.shape
     err = float(jnp.max(jnp.abs(o - r)))
@@ -63,7 +67,8 @@ def test_flash_attention_shape_sweep(b, n_blocks, h_pow, g_pow, dh, causal):
 
 def test_flash_attention_mqa():
     q, k, v = _qkv(jax.random.PRNGKey(9), 2, 128, 8, 1, 64, jnp.float32)
-    o = flash_attention_op(q, k, v, causal=True, q_block=64, kv_block=64)
+    o = flash_attention(q, k, v, causal=True, q_block=64, kv_block=64,
+                           interpret=True)
     r = attention_reference(q, k, v, causal=True)
     assert float(jnp.max(jnp.abs(o - r))) < 2e-5
 
@@ -78,7 +83,7 @@ def test_flash_attention_mqa():
 def test_rmsnorm_sweep(rows, d, dtype):
     x = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), dtype)
     s = jax.random.normal(jax.random.PRNGKey(d), (d,), jnp.float32)
-    o = rmsnorm_op(x, s, block_rows=64)
+    o = rmsnorm(x, s, block_rows=64, interpret=True)
     r = rmsnorm_reference(x, s)
     err = float(jnp.max(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32))))
     assert err < (1e-5 if dtype == jnp.float32 else 5e-2)
@@ -100,7 +105,7 @@ def test_gbt_kernel_sweep(n, n_estimators, depth, seed):
     y = np.sin(X[:, 0]) + X[:, 1]
     m = GBTRegressor(GBTConfig(n_estimators=n_estimators, max_depth=depth)).fit(X, y)
     ens = m.ensemble
-    pk = np.asarray(gbt_predict_op(X, ens, row_block=64))
+    pk = np.asarray(gbt_predict_op(X, ens, row_block=64, interpret=True))
     pn = m.predict(X)  # numpy/JAX reference path
     np.testing.assert_allclose(pk, pn, rtol=1e-4, atol=1e-4)
 
@@ -111,12 +116,29 @@ def test_gbt_kernel_vs_jnp_oracle(synth_regression):
     X, y = synth_regression
     m = GBTRegressor(GBTConfig(n_estimators=12, max_depth=4)).fit(X, y)
     ens = m.ensemble
-    pk = gbt_predict_op(X, ens, row_block=128)
+    pk = gbt_predict_op(X, ens, row_block=128, interpret=True)
     pr = gbt_predict_reference(
         jnp.asarray(X, jnp.float32), ens.feature, ens.threshold, ens.left,
         ens.right, ens.value, ens.max_depth, ens.base_score, ens.scale,
     )
     np.testing.assert_allclose(np.asarray(pk), np.asarray(pr), rtol=1e-5, atol=1e-5)
+
+
+def test_gbt_table_split_is_exact():
+    """Node-table entries (thresholds, values, node indices past bf16's 256)
+    split into bf16 parts whose f32 sum is the entry bit for bit, and each
+    part is a bf16 value before its cast (no rounding left to elide)."""
+    from repro.kernels.gbt_predict import _bf16_head, _split3
+
+    rng = np.random.default_rng(0)
+    v = np.concatenate([
+        rng.normal(size=4096) * 10.0 ** rng.integers(-6, 6, 4096),
+        np.arange(-4096, 4096), [0.0, -1.0, 3.0e38],
+    ]).astype(np.float32)
+    hi, mid, lo = (np.asarray(p, np.float32) for p in jax.jit(_split3)(v))
+    np.testing.assert_array_equal((hi + mid) + lo, v)
+    head = np.asarray(_bf16_head(jnp.asarray(v)))
+    np.testing.assert_array_equal(head, head.astype(jnp.bfloat16).astype(np.float32))
 
 
 # ---------------------------------------------------------------- mamba scan

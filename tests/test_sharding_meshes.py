@@ -113,10 +113,11 @@ with mesh:
                        out_shardings=named(b.out_shardings),
                        donate_argnums=b.donate_argnums).lower(*b.abstract_inputs).compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):  # older jax returns one dict per program
-    ca = ca[0] if ca else {{}}
 print(json.dumps({{"ok": True, "flops": (ca or {{}}).get("flops", 0)}}))
 """
+
+
+_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _run_mesh(arch, shape, data, model, pod=0, multi_pod=False):
@@ -125,7 +126,7 @@ def _run_mesh(arch, shape, data, model, pod=0, multi_pod=False):
                                  model=model, pod=pod, multi_pod=multi_pod)
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, cwd="/root/repo", timeout=600, env=env)
+                       text=True, cwd=_REPO, timeout=600, env=env)
     assert r.returncode == 0, r.stderr[-3000:]
     return json.loads(r.stdout.strip().splitlines()[-1])
 

@@ -459,11 +459,17 @@ def test_recommend_chunked_matches_oracle_paper_grid(model):
         )
 
 
-def test_recommend_pallas_kernel_matches_oracle_paper_grid():
-    """The Pallas one-hot-matmul kernel (interpret mode off-TPU) and the
-    numpy oracle pick the identical top-k on the paper 1,800-config grid."""
-    from repro.core import ConfigSpace, recommend
+def test_recommend_pallas_kernel_matches_oracle_paper_grid(monkeypatch):
+    """The Pallas one-hot-matmul kernel and the numpy oracle pick the
+    identical top-k on the paper 1,800-config grid.  The program compiles
+    the kernel; off the chip this test runs it in the Pallas interpreter."""
+    import functools
 
+    from repro.core import ConfigSpace, recommend
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "gbt_predict_op",
+                        functools.partial(ops.gbt_predict_op, interpret=True))
     pred = _fitted_predictor("xgboost")
     ctx = {"throughput_mb_s": 800.0, "file_size_mb": 64.0, "iops": 5e4}
     space = ConfigSpace()
