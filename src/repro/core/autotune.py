@@ -34,6 +34,7 @@ import threading
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .ensemble_base import PackedEnsemble, ceil_pow2, predict_ensemble
 from .features import AUTOTUNE_FEATURE_NAMES, FeatureSpec
@@ -156,6 +157,26 @@ MEGA_GRID_MIN = 4096
 MEGA_GRID_CHUNK = 8192
 _MEGA_TAIL_FLOOR = 256
 
+RECOMMEND_SPANS = ("repro.recommend", "repro.grid.assemble", "repro.grid.dispatch",
+                   "repro.grid.fetch", "repro.recommend.select")
+"""Host spans of the recommend path, as a ``jax.profiler`` trace shows them.
+
+On the host thread that calls ``recommend()``, each call is one
+``repro.recommend`` span; its time that no child covers is host work outside
+the phases below.  Inside it, on the packed (mega-grid) path, every chunk
+opens three spans in turn: ``repro.grid.assemble`` (the float32 chunk buffer
+filled from the cached knob columns), ``repro.grid.dispatch`` (the
+host-to-device copy of the chunk and the launch of the descent program) and
+``repro.grid.fetch`` (the wait for the device and the copy of the chunk's
+scores back).  Last comes ``repro.recommend.select``: the top-k, the winners'
+dicts and, on the packed path, their oracle re-score.  The oracle path opens
+only the first and the last.  The device's own programs and operations lie on
+the same clock, so an idle gap of the device falls inside the span of what the
+host was doing then.  With no profiler recording, a span costs only its enter
+and exit."""
+(_SPAN_RECOMMEND, _SPAN_ASSEMBLE, _SPAN_DISPATCH, _SPAN_FETCH,
+ _SPAN_SELECT) = RECOMMEND_SPANS
+
 
 def _packed_model(predictor) -> Optional[PackedEnsemble]:
     """The predictor's ``PackedEnsemble`` when its ``predict`` is exactly the
@@ -212,23 +233,26 @@ def _score_grid_packed(
     buffers: Dict[int, np.ndarray] = {}
     lo = 0
     while lo < n:
-        rows = min(chunk, n - lo)
-        padded = chunk if rows == chunk else ceil_pow2(rows, _MEGA_TAIL_FLOOR)
-        buf = buffers.get(padded)
-        if buf is None:
-            buf = np.zeros((padded, len(names)), np.float32)
-            for j, v in ctx_vals:
-                buf[:, j] = v
-            buffers[padded] = buf
-        for j, col in knob_cols:
-            buf[:rows, j] = col[lo : lo + rows]
-            if rows < padded:
-                buf[rows:, j] = 0.0
-        if pallas:
-            out = gbt_predict_op(buf, ens)
-        else:
-            out = predict_ensemble(ens, buf)
-        scores[lo : lo + rows] = np.asarray(out)[:rows]
+        with TraceAnnotation(_SPAN_ASSEMBLE):
+            rows = min(chunk, n - lo)
+            padded = chunk if rows == chunk else ceil_pow2(rows, _MEGA_TAIL_FLOOR)
+            buf = buffers.get(padded)
+            if buf is None:
+                buf = np.zeros((padded, len(names)), np.float32)
+                for j, v in ctx_vals:
+                    buf[:, j] = v
+                buffers[padded] = buf
+            for j, col in knob_cols:
+                buf[:rows, j] = col[lo : lo + rows]
+                if rows < padded:
+                    buf[rows:, j] = 0.0
+        with TraceAnnotation(_SPAN_DISPATCH):
+            if pallas:
+                out = gbt_predict_op(buf, ens)
+            else:
+                out = predict_ensemble(ens, buf)
+        with TraceAnnotation(_SPAN_FETCH):
+            scores[lo : lo + rows] = np.asarray(out)[:rows]
         lo += rows
     return scores
 
@@ -282,34 +306,36 @@ def recommend(
     the reported ``predicted_throughput_mb_s`` values are identical to what
     the numpy baseline would report.
     """
-    scores, mode = score_grid(predictor, context, space, scorer=scorer, chunk=chunk)
-    n = scores.shape[0]
-    k = min(top_k, n)
-    if k < n:
-        part = np.argpartition(-scores, k - 1)[:k]
-        order = part[np.argsort(scores[part])[::-1]]
-    else:
-        order = np.argsort(scores)[::-1]
-    winners = [space.candidate(i) for i in order]
-    if mode == "oracle":
-        pred_k = scores[order]
-    else:
-        names = predictor.spec.names
-        Xk = np.empty((k, len(names)), np.float64)
-        for r, cand in enumerate(winners):
-            for j, name in enumerate(names):
-                Xk[r, j] = (
-                    float(cand[name]) if name in KNOB_NAMES
-                    else float(context.get(name, 0.0))
-                )
-        pred_k = np.asarray(predictor.predict_throughput_batch(Xk))
-        resort = np.argsort(-pred_k, kind="stable")
-        winners = [winners[int(r)] for r in resort]
-        pred_k = pred_k[resort]
-    return [
-        {**cand, "predicted_throughput_mb_s": float(pred_k[r])}
-        for r, cand in enumerate(winners)
-    ]
+    with TraceAnnotation(_SPAN_RECOMMEND):
+        scores, mode = score_grid(predictor, context, space, scorer=scorer, chunk=chunk)
+        with TraceAnnotation(_SPAN_SELECT):
+            n = scores.shape[0]
+            k = min(top_k, n)
+            if k < n:
+                part = np.argpartition(-scores, k - 1)[:k]
+                order = part[np.argsort(scores[part])[::-1]]
+            else:
+                order = np.argsort(scores)[::-1]
+            winners = [space.candidate(i) for i in order]
+            if mode == "oracle":
+                pred_k = scores[order]
+            else:
+                names = predictor.spec.names
+                Xk = np.empty((k, len(names)), np.float64)
+                for r, cand in enumerate(winners):
+                    for j, name in enumerate(names):
+                        Xk[r, j] = (
+                            float(cand[name]) if name in KNOB_NAMES
+                            else float(context.get(name, 0.0))
+                        )
+                pred_k = np.asarray(predictor.predict_throughput_batch(Xk))
+                resort = np.argsort(-pred_k, kind="stable")
+                winners = [winners[int(r)] for r in resort]
+                pred_k = pred_k[resort]
+            return [
+                {**cand, "predicted_throughput_mb_s": float(pred_k[r])}
+                for r, cand in enumerate(winners)
+            ]
 
 
 @dataclasses.dataclass

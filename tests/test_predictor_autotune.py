@@ -337,3 +337,50 @@ def test_predictor_engine_argument_overrides_env(monkeypatch):
     cols["target_throughput"] = rng.random(40) * 100 + 10
     IOPerformancePredictor(model="xgboost", engine="level").fit(cols)
     assert calls, "explicit engine= was not honored"
+
+
+def test_recommend_spans_in_a_profiler_trace(tmp_path):
+    """A mega-grid recommend() under jax.profiler shows one repro.recommend
+    span holding, per chunk, assemble, dispatch and fetch in that order, and
+    then one repro.recommend.select; an active trace changes no pick."""
+    import math
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core import FEATURE_NAMES
+    from repro.core.autotune import MEGA_GRID_MIN, RECOMMEND_SPANS
+
+    rng = np.random.default_rng(0)
+    cols = {name: rng.uniform(1, 100, 240) for name in FEATURE_NAMES}
+    cols["target_throughput"] = rng.uniform(10, 500, 240) + 2.0 * cols[FEATURE_NAMES[0]]
+    pred = IOPerformancePredictor(model="xgboost").fit(cols)
+    space = ConfigSpace(prefetch_policy=(0, 1), lookahead_batches=(4, 8),
+                        cache_budget_mb=(32.0, 64.0))
+    n, chunk = space.n_candidates, 2048
+    assert n >= MEGA_GRID_MIN
+    ctx = {"throughput_mb_s": 800.0, "file_size_mb": 64.0}
+    untraced = recommend(pred, ctx, space, top_k=5, scorer="chunked", chunk=chunk)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced = recommend(pred, ctx, space, top_k=5, scorer="chunked", chunk=chunk)
+    finally:
+        jax.profiler.stop_trace()
+    assert traced == untraced
+
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = sorted((e.start_ns, e.end_ns, e.name)
+                   for plane in ProfileData.from_file(str(path)).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name in RECOMMEND_SPANS)
+    by_name = {name: [(s, e) for s, e, nm in spans if nm == name] for name in RECOMMEND_SPANS}
+    call, assemble, dispatch, fetch, select = (by_name[name] for name in RECOMMEND_SPANS)
+    assert len(call) == 1 and len(select) == 1
+    chunks = math.ceil(n / chunk)
+    assert len(assemble) == len(dispatch) == len(fetch) == chunks
+    lo, hi = call[0]
+    phases = [iv for triple in zip(assemble, dispatch, fetch) for iv in triple] + select
+    assert all(lo <= s <= e <= hi for s, e in phases)
+    # one after another, never overlapping: assemble, dispatch, fetch per chunk
+    assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
