@@ -8,12 +8,11 @@ Two layers:
    ONE batched JAX ensemble inference (milliseconds for 10^5 candidates) over
    a cached feature matrix — per ``decide()`` only the scalar context columns
    are rewritten in place (zero per-candidate Python work).  Mega grids
-   (``MEGA_GRID_MIN``+ candidates) with a GBT/RF predictor are scored in
-   fixed-size float32 chunks through the packed-ensemble program — the Pallas
-   one-hot-matmul kernel on TPU, the jitted dense descent elsewhere — so the
-   per-tree intermediates stay VMEM/cache-resident instead of spilling
-   O(n_candidates x n_trees) floats to DRAM; the classic numpy path remains
-   the oracle (``scorer="oracle"``).
+   (``MEGA_GRID_MIN``+ candidates) with a GBT/RF predictor are built, scored
+   and ranked in one device program per call — the Pallas one-hot-matmul
+   kernel on TPU, the jitted gather descent elsewhere — so only the call's
+   context goes to the device and only the top-k comes back; the classic
+   numpy path remains the oracle (``scorer="oracle"``).
 
 2. ``OnlineAutotuner`` — the framework integration: lives inside the trainer
    (step-granularity telemetry) or behind the ``repro.service`` loop/fleet
@@ -29,14 +28,18 @@ Two layers:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
+import math
 import threading
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from .ensemble_base import PackedEnsemble, ceil_pow2, predict_ensemble
+from .ensemble_base import PackedEnsemble, predict_ensemble
 from .features import AUTOTUNE_FEATURE_NAMES, FeatureSpec
 from .predictor import IOPerformancePredictor, PredictorSnapshot
 
@@ -147,15 +150,17 @@ DEFAULT_SPACE = ConfigSpace()
 
 
 # -- mega-grid scoring -----------------------------------------------------
-# Above MEGA_GRID_MIN candidates, an ensemble-backed recommend() stops
-# materializing the [n, F] float64 matrix + one monolithic inference and
-# instead scores fixed-size float32 chunks assembled straight from the cached
-# knob columns.  Chunks are MEGA_GRID_CHUNK rows; the tail is padded to a
-# power of two (floor _MEGA_TAIL_FLOOR) so the jit cache stays logarithmic in
-# the grid size, exactly like the serving tier's micro-batch buckets.
+# Above MEGA_GRID_MIN candidates, an ensemble-backed recommend() scores the
+# whole grid in ONE device program per call.  Candidate i (itertools.product
+# order over KNOB_NAMES) is a mixed-radix index over the grid's shape: the
+# program decodes an iota into per-knob digits and looks each up in a small
+# float32 table of that knob's values, so only the call's [F] context vector
+# goes to the device and only the top-k indices (or, for score_grid, the
+# scores) come back.  Off the TPU the gather descent runs over
+# MEGA_GRID_CHUNK-row blocks inside the program, so memory stays at one
+# block's worth.
 MEGA_GRID_MIN = 4096
 MEGA_GRID_CHUNK = 8192
-_MEGA_TAIL_FLOOR = 256
 
 RECOMMEND_SPANS = ("repro.recommend", "repro.grid.assemble", "repro.grid.dispatch",
                    "repro.grid.fetch", "repro.recommend.select")
@@ -163,17 +168,18 @@ RECOMMEND_SPANS = ("repro.recommend", "repro.grid.assemble", "repro.grid.dispatc
 
 On the host thread that calls ``recommend()``, each call is one
 ``repro.recommend`` span; its time that no child covers is host work outside
-the phases below.  Inside it, on the packed (mega-grid) path, every chunk
-opens three spans in turn: ``repro.grid.assemble`` (the float32 chunk buffer
-filled from the cached knob columns), ``repro.grid.dispatch`` (the
-host-to-device copy of the chunk and the launch of the descent program) and
-``repro.grid.fetch`` (the wait for the device and the copy of the chunk's
-scores back).  Last comes ``repro.recommend.select``: the top-k, the winners'
-dicts and, on the packed path, their oracle re-score.  The oracle path opens
-only the first and the last.  The device's own programs and operations lie on
-the same clock, so an idle gap of the device falls inside the span of what the
-host was doing then.  With no profiler recording, a span costs only its enter
-and exit."""
+the phases below.  Inside it, on the packed (mega-grid) path, the grid
+program opens three spans in turn, once per call: ``repro.grid.assemble``
+(the call's float32 context vector), ``repro.grid.dispatch`` (the launch of
+the program that builds, scores and ranks every candidate on the device) and
+``repro.grid.fetch`` (the wait for that program and the copy of the top-k
+back, so it spans the device's whole work on the grid).  Last comes
+``repro.recommend.select``: the winners' dicts and, on the packed path,
+their oracle re-score; on the oracle path also the top-k.  The oracle path
+opens only the first and the last.  The device's own programs and
+operations lie on the same clock, so an idle gap of the device falls inside
+the span of what the host was doing then.  With no profiler recording, a
+span costs only its enter and exit."""
 (_SPAN_RECOMMEND, _SPAN_ASSEMBLE, _SPAN_DISPATCH, _SPAN_FETCH,
  _SPAN_SELECT) = RECOMMEND_SPANS
 
@@ -186,8 +192,6 @@ def _packed_model(predictor) -> Optional[PackedEnsemble]:
 
 
 def _on_tpu() -> bool:
-    import jax
-
     return jax.default_backend() == "tpu"
 
 
@@ -203,58 +207,122 @@ def _resolve_scorer(scorer: str, ens: Optional[PackedEnsemble], n: int) -> str:
     return scorer
 
 
+def _knob_table(space: ConfigSpace) -> jax.Array:
+    """Every knob's values, in ``KNOB_NAMES`` order, as one float32 device
+    table (cached on the space): the float32 of the float64 values that
+    ``knob_columns()`` holds."""
+    table = space._cache.get("knob_table")
+    if table is None:
+        vals = [np.asarray(getattr(space, k), np.float64) for k in KNOB_NAMES]
+        table = jnp.asarray(np.concatenate(vals).astype(np.float32))
+        space._cache["knob_table"] = table
+    return table
+
+
+def _candidate_columns(idx, ctx, table, radices, knob_of):
+    """Feature columns of the candidates ``idx`` ([m] int32): feature ``j``
+    is knob ``knob_of[j]``'s value, digit ``idx // stride % radix`` of the
+    mixed-radix index, or, where ``knob_of[j]`` is -1, ``ctx[j]``."""
+    strides = [math.prod(radices[i + 1:]) for i in range(len(radices))]
+    offsets = [sum(radices[:i]) for i in range(len(radices))]
+    knobs = []
+    for radix, stride, off in zip(radices, strides, offsets):
+        digit = idx // stride % radix
+        col = jnp.broadcast_to(table[off], idx.shape)
+        for v in range(1, radix):
+            col = jnp.where(digit == v, table[off + v], col)
+        knobs.append(col)
+    return [knobs[k] if k >= 0 else jnp.broadcast_to(ctx[j], idx.shape)
+            for j, k in enumerate(knob_of)]
+
+
+@functools.partial(jax.jit, static_argnames=("radices", "knob_of", "max_depth",
+                                             "pallas", "descent", "top_k"))
+def _grid_program(ctx, table, trees, base, scale, *, radices, knob_of, max_depth,
+                  pallas, descent, top_k):
+    """Float32 log scores of every grid candidate, built and scored on the
+    device: the ``top_k`` best indices (highest first, equal scores at the
+    lower index), or with ``top_k=None`` all ``n`` scores.
+
+    ``descent`` is ``kernels.ops.gbt_predict_op`` where ``pallas`` (one
+    ``pallas_call`` over every row block), else ``predict_ensemble``, run
+    block by block.  Each row's float32 features and descent are those of
+    its row of the float32 feature matrix, whatever the batch, so each real
+    row's score is too."""
+    n = math.prod(radices)
+    ens = PackedEnsemble(**trees, max_depth=max_depth, base_score=base, scale=scale)
+    if not pallas:
+        unbased = dataclasses.replace(ens, base_score=0.0)
+
+        def block(b):
+            idx = b * MEGA_GRID_CHUNK + jax.lax.iota(jnp.int32, MEGA_GRID_CHUNK)
+            X = jnp.stack(_candidate_columns(idx, ctx, table, radices, knob_of), axis=1)
+            return descent(unbased, X)
+
+        # The base is added outside the loop: predict_ensemble rounds its
+        # product and its sum apart, and in one fusion the CPU backend would
+        # contract the two into a fused multiply-add that rounds once.
+        blocks = jnp.arange(-(-n // MEGA_GRID_CHUNK), dtype=jnp.int32)
+        scores = base + jax.lax.map(block, blocks).reshape(-1)
+    else:
+        from ..kernels.gbt_predict import kernel_rows
+
+        n_rows = kernel_rows(trees["feature"].shape[1], n)
+        idx = jax.lax.iota(jnp.int32, n_rows)
+        cols = _candidate_columns(idx, ctx, table, radices, knob_of)
+        cols += [jnp.zeros_like(cols[0])] * (-len(cols) % 8)
+        # [f_pad, n_rows]: the kernel's rows-on-lanes layout, padded to whole
+        # row blocks and sublane tiles, so the op's own pad is empty and its
+        # transpose cancels the one here.
+        xt = jnp.stack(cols, axis=0)
+        scores = descent(xt.T, ens)
+    if top_k is None:
+        return scores[:n]
+    live = jax.lax.iota(jnp.int32, scores.shape[0]) < n
+    return _device_top_k(jnp.where(live, scores, -jnp.inf), top_k)
+
+
+def _device_top_k(scores, k: int):
+    """Exact top-k indices of ``scores``, highest first, equal scores at the
+    lower index: ``k`` argmax passes, each striking out its winner.  Equal
+    to ``lax.top_k``'s indices, which on the TPU sorts all n rows and takes
+    tens of seconds to compile at 10^6."""
+    def step(j, carry):
+        scores, top = carry
+        i = jnp.argmax(scores).astype(jnp.int32)
+        return scores.at[i].set(-jnp.inf), top.at[j].set(i)
+
+    return jax.lax.fori_loop(0, k, step, (scores, jnp.zeros(k, jnp.int32)))[1]
+
+
 def _score_grid_packed(
     ens: PackedEnsemble,
     spec: FeatureSpec,
     space: ConfigSpace,
     context: dict,
     *,
-    chunk: int,
     pallas: bool,
+    top_k: Optional[int],
 ) -> np.ndarray:
-    """Float32 log-space scores of every grid candidate, chunk by chunk.
-
-    Each [chunk, F] block is written into a reused float32 buffer: knob
-    columns sliced from the cached grid, context features (chunk-invariant)
-    filled once per buffer shape.  Pad rows are scored and discarded — per-row
-    descent is independent, so padding never changes a real row."""
-    n = space.n_candidates
-    cols = space.knob_columns()
-    names = spec.names
-    knob_cols = [(j, cols[name]) for j, name in enumerate(names) if name in KNOB_NAMES]
-    ctx_vals = [
-        (j, float(context.get(name, 0.0)))
-        for j, name in enumerate(names)
-        if name not in KNOB_NAMES
-    ]
+    """One ``_grid_program`` call: the host sends the call's context and
+    gets back the top-k indices, or all float32 log scores."""
+    descent = predict_ensemble
     if pallas:
-        from ..kernels.ops import gbt_predict_op
-    scores = np.empty(n, np.float32)
-    buffers: Dict[int, np.ndarray] = {}
-    lo = 0
-    while lo < n:
-        with TraceAnnotation(_SPAN_ASSEMBLE):
-            rows = min(chunk, n - lo)
-            padded = chunk if rows == chunk else ceil_pow2(rows, _MEGA_TAIL_FLOOR)
-            buf = buffers.get(padded)
-            if buf is None:
-                buf = np.zeros((padded, len(names)), np.float32)
-                for j, v in ctx_vals:
-                    buf[:, j] = v
-                buffers[padded] = buf
-            for j, col in knob_cols:
-                buf[:rows, j] = col[lo : lo + rows]
-                if rows < padded:
-                    buf[rows:, j] = 0.0
-        with TraceAnnotation(_SPAN_DISPATCH):
-            if pallas:
-                out = gbt_predict_op(buf, ens)
-            else:
-                out = predict_ensemble(ens, buf)
-        with TraceAnnotation(_SPAN_FETCH):
-            scores[lo : lo + rows] = np.asarray(out)[:rows]
-        lo += rows
-    return scores
+        from ..kernels import ops
+
+        descent = ops.gbt_predict_op
+    with TraceAnnotation(_SPAN_ASSEMBLE):
+        ctx = np.asarray([0.0 if name in KNOB_NAMES else float(context.get(name, 0.0))
+                          for name in spec.names], np.float32)
+        knob_of = tuple(KNOB_NAMES.index(name) if name in KNOB_NAMES else -1
+                        for name in spec.names)
+    with TraceAnnotation(_SPAN_DISPATCH):
+        out = _grid_program(
+            ctx, _knob_table(space), ens.tree_dict(), np.float32(ens.base_score),
+            np.float32(ens.scale), radices=space._grid_shape(), knob_of=knob_of,
+            max_depth=ens.max_depth, pallas=pallas, descent=descent, top_k=top_k)
+    with TraceAnnotation(_SPAN_FETCH):
+        return np.asarray(out)
 
 
 def score_grid(
@@ -263,7 +331,6 @@ def score_grid(
     space: ConfigSpace = DEFAULT_SPACE,
     *,
     scorer: str = "auto",
-    chunk: int = MEGA_GRID_CHUNK,
 ) -> Tuple[np.ndarray, str]:
     """Score every candidate in the grid; returns ``(scores, mode)``.
 
@@ -273,7 +340,7 @@ def score_grid(
     monotone, so the ranking is the same and the mega path skips n expm1s).
     ``scorer="auto"`` picks the packed path for ensemble models on grids of
     ``MEGA_GRID_MIN``+ candidates — the Pallas kernel on TPU, the jitted
-    dense descent elsewhere — and the oracle otherwise; forcing
+    gather descent elsewhere — and the oracle otherwise; forcing
     ``"chunked"``/``"pallas"`` on a non-ensemble model falls back to oracle.
     """
     ens = _packed_model(predictor)
@@ -282,12 +349,22 @@ def score_grid(
         X = space.feature_matrix(predictor.spec, context)
         return np.asarray(predictor.predict_throughput_batch(X)), mode
     return (
-        _score_grid_packed(
-            ens, predictor.spec, space, context, chunk=chunk,
-            pallas=(mode == "pallas"),
-        ),
+        _score_grid_packed(ens, predictor.spec, space, context,
+                           pallas=(mode == "pallas"), top_k=None),
         mode,
     )
+
+
+def _top_k_indices(scores: np.ndarray, k: int) -> np.ndarray:
+    """Indices of the ``k`` highest scores, highest first, equal scores at
+    the lower index (the device top-k's order), in O(n)."""
+    n = scores.shape[0]
+    idx = np.arange(n)
+    if 0 < k < n:
+        kth = np.partition(scores, n - k)[n - k]
+        above = np.flatnonzero(scores > kth)
+        idx = np.concatenate([above, np.flatnonzero(scores == kth)[: k - above.size]])
+    return idx[np.lexsort((idx, -scores[idx]))][:k]
 
 
 def recommend(
@@ -296,30 +373,33 @@ def recommend(
     space: ConfigSpace = DEFAULT_SPACE,
     top_k: int = 5,
     scorer: str = "auto",
-    chunk: int = MEGA_GRID_CHUNK,
 ) -> List[dict]:
     """Ranked top-k configurations by predicted throughput.
 
-    One grid scoring (see ``score_grid``) + an O(n) argpartition; only the k
-    winning candidate dicts are built.  When the mega-grid path scored in
-    float32 log space, the winners are re-scored through the oracle path so
-    the reported ``predicted_throughput_mb_s`` values are identical to what
-    the numpy baseline would report.
+    Both paths take an exact top-k, equal scores at the lower index, and
+    build only the k winning candidate dicts.  The oracle path scores the
+    grid (see ``score_grid``) and partitions on the host in O(n).  The
+    packed (mega-grid) path ranks the float32 log scores on the device and
+    re-scores the winners through the oracle path, so the reported
+    ``predicted_throughput_mb_s`` values, and their order, are identical to
+    what the numpy baseline would report.
     """
     with TraceAnnotation(_SPAN_RECOMMEND):
-        scores, mode = score_grid(predictor, context, space, scorer=scorer, chunk=chunk)
+        ens = _packed_model(predictor)
+        n = space.n_candidates
+        k = min(top_k, n)
+        mode = _resolve_scorer(scorer, ens, n)
+        if mode == "oracle":
+            scores, _ = score_grid(predictor, context, space, scorer="oracle")
+        else:
+            order = _score_grid_packed(ens, predictor.spec, space, context,
+                                       pallas=(mode == "pallas"), top_k=k)
         with TraceAnnotation(_SPAN_SELECT):
-            n = scores.shape[0]
-            k = min(top_k, n)
-            if k < n:
-                part = np.argpartition(-scores, k - 1)[:k]
-                order = part[np.argsort(scores[part])[::-1]]
-            else:
-                order = np.argsort(scores)[::-1]
-            winners = [space.candidate(i) for i in order]
             if mode == "oracle":
+                order = _top_k_indices(scores, k)
                 pred_k = scores[order]
-            else:
+            winners = [space.candidate(i) for i in order]
+            if mode != "oracle":
                 names = predictor.spec.names
                 Xk = np.empty((k, len(names)), np.float64)
                 for r, cand in enumerate(winners):

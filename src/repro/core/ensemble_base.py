@@ -31,9 +31,9 @@ __all__ = [
 def ceil_pow2(n: int, floor: int = 1) -> int:
     """Smallest power of two >= max(n, floor).
 
-    Shared by the serving tier's micro-batcher and the mega-grid scorer's
-    tail chunk: padding row counts to powers of two keeps the number of
-    distinct jit-compiled shapes logarithmic in the batch-size range."""
+    The serving tier's micro-batcher pads row counts to powers of two, so
+    the number of distinct jit-compiled shapes stays logarithmic in the
+    batch-size range."""
     return 1 << max(max(int(n), int(floor)) - 1, 0).bit_length()
 
 

@@ -124,6 +124,13 @@ def _row_block(n_pad: int, n_rows: int, row_block) -> int:
     return min(want, cap, _round_up(n_rows, 128))
 
 
+def kernel_rows(n_nodes: int, n_rows: int) -> int:
+    """Rows ``gbt_predict`` scores for ``n_rows`` inputs with its default row
+    block: ``n_rows`` rounded up to whole blocks.  An input of this many
+    rows is scored with no row padding."""
+    return _round_up(n_rows, _row_block(_round_up(n_nodes, 128), n_rows, None))
+
+
 @functools.partial(jax.jit, static_argnames=("max_depth", "row_block", "interpret"))
 def gbt_predict(
     X, feature, threshold, left, right, value, *,

@@ -18,6 +18,6 @@ def gbt_predict_op(X, ensemble, *, row_block=None, interpret=False):
         jnp.asarray(X, jnp.float32),
         ensemble.feature, ensemble.threshold, ensemble.left, ensemble.right,
         ensemble.value, max_depth=ensemble.max_depth,
-        base_score=float(ensemble.base_score), scale=float(ensemble.scale),
+        base_score=ensemble.base_score, scale=ensemble.scale,
         row_block=row_block, interpret=interpret,
     )

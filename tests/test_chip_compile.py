@@ -1,5 +1,6 @@
 """Ahead-of-time compiles of the main path's device programs for a described
-TPU v5e, at real widths: the Pallas mega-grid kernel and the jitted gather
+TPU v5e, at real widths: the Pallas mega-grid kernel, the whole-grid program
+that ``recommend()`` runs over 10^6 candidates, and the jitted gather
 descent that serves ``/predict`` and ``/recommend``.
 
 Nothing runs: the TPU compiler refuses here what the chip would refuse
@@ -15,10 +16,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import FEATURE_NAMES
-from repro.core.autotune import _MEGA_TAIL_FLOOR, MEGA_GRID_CHUNK
+from repro.core.autotune import KNOB_NAMES, _grid_program
 from repro.core.ensemble_base import _predict_packed
 from repro.core.features import AUTOTUNE_FEATURE_NAMES
 from repro.core.predictor import make_model
+from repro.kernels import ops
 from repro.kernels.gbt_predict import gbt_predict
 from repro.service.serve import ServeConfig
 
@@ -64,7 +66,7 @@ def _tables(sharding, trees: int, nodes: int):
     return s(jnp.int32), s(jnp.float32), s(jnp.int32), s(jnp.int32), s(jnp.float32)
 
 
-@pytest.mark.parametrize("rows", [MEGA_GRID_CHUNK, _MEGA_TAIL_FLOOR])
+@pytest.mark.parametrize("rows", [8192, 256])
 @pytest.mark.parametrize("model", ["xgboost", "random_forest"])
 def test_gbt_kernel_compiles_for_v5e(one_chip, model, rows):
     trees, depth, nodes = _zoo_widths(model)
@@ -75,6 +77,36 @@ def test_gbt_kernel_compiles_for_v5e(one_chip, model, rows):
         x, *_tables(one_chip, trees, nodes), max_depth=depth,
         base_score=scalar, scale=scalar).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The 10^6-candidate grid of the benchmark's recommend cell and chip_smoke.py.
+_GRID_1E6 = (10, 10, 10, 10, 10, 2, 5, 1)
+
+
+@pytest.mark.parametrize("top_k", [5, None])
+@pytest.mark.parametrize("model", ["xgboost", "random_forest"])
+def test_grid_program_compiles_for_v5e(one_chip, model, top_k):
+    """recommend()'s (top-k) and score_grid()'s (all scores) whole-grid
+    program: one compiled Pallas kernel over every row, in well under 1 GB."""
+    trees, depth, nodes = _zoo_widths(model)
+    names = ("feature", "threshold", "left", "right", "value")
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = _grid_program.lower(
+        s((len(FEATURE_NAMES),)), s((sum(_GRID_1E6),)),
+        dict(zip(names, _tables(one_chip, trees, nodes))), s(()), s(()),
+        radices=_GRID_1E6,
+        knob_of=tuple(KNOB_NAMES.index(n) if n in KNOB_NAMES else -1
+                      for n in FEATURE_NAMES),
+        max_depth=depth, pallas=True, descent=ops.gbt_predict_op,
+        top_k=top_k).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 2**30
 
 
 def test_serving_descent_compiles_for_v5e(one_chip):

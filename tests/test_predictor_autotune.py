@@ -341,15 +341,14 @@ def test_predictor_engine_argument_overrides_env(monkeypatch):
 
 def test_recommend_spans_in_a_profiler_trace(tmp_path):
     """A mega-grid recommend() under jax.profiler shows one repro.recommend
-    span holding, per chunk, assemble, dispatch and fetch in that order, and
-    then one repro.recommend.select; an active trace changes no pick."""
-    import math
-
+    span holding one assemble, one dispatch and one fetch, in that order,
+    whatever the grid's size, and then one repro.recommend.select; an
+    active trace changes no pick."""
     import jax
     from jax.profiler import ProfileData
 
     from repro.core import FEATURE_NAMES
-    from repro.core.autotune import MEGA_GRID_MIN, RECOMMEND_SPANS
+    from repro.core.autotune import MEGA_GRID_CHUNK, MEGA_GRID_MIN, RECOMMEND_SPANS
 
     rng = np.random.default_rng(0)
     cols = {name: rng.uniform(1, 100, 240) for name in FEATURE_NAMES}
@@ -357,13 +356,13 @@ def test_recommend_spans_in_a_profiler_trace(tmp_path):
     pred = IOPerformancePredictor(model="xgboost").fit(cols)
     space = ConfigSpace(prefetch_policy=(0, 1), lookahead_batches=(4, 8),
                         cache_budget_mb=(32.0, 64.0))
-    n, chunk = space.n_candidates, 2048
-    assert n >= MEGA_GRID_MIN
+    n = space.n_candidates
+    assert n >= MEGA_GRID_MIN and n > MEGA_GRID_CHUNK  # more than one block
     ctx = {"throughput_mb_s": 800.0, "file_size_mb": 64.0}
-    untraced = recommend(pred, ctx, space, top_k=5, scorer="chunked", chunk=chunk)
+    untraced = recommend(pred, ctx, space, top_k=5, scorer="chunked")
     jax.profiler.start_trace(str(tmp_path))
     try:
-        traced = recommend(pred, ctx, space, top_k=5, scorer="chunked", chunk=chunk)
+        traced = recommend(pred, ctx, space, top_k=5, scorer="chunked")
     finally:
         jax.profiler.stop_trace()
     assert traced == untraced
@@ -377,10 +376,10 @@ def test_recommend_spans_in_a_profiler_trace(tmp_path):
     by_name = {name: [(s, e) for s, e, nm in spans if nm == name] for name in RECOMMEND_SPANS}
     call, assemble, dispatch, fetch, select = (by_name[name] for name in RECOMMEND_SPANS)
     assert len(call) == 1 and len(select) == 1
-    chunks = math.ceil(n / chunk)
-    assert len(assemble) == len(dispatch) == len(fetch) == chunks
+    # one grid program per call: the engagement counter of the device path
+    assert len(assemble) == len(dispatch) == len(fetch) == 1
     lo, hi = call[0]
-    phases = [iv for triple in zip(assemble, dispatch, fetch) for iv in triple] + select
+    phases = assemble + dispatch + fetch + select
     assert all(lo <= s <= e <= hi for s, e in phases)
-    # one after another, never overlapping: assemble, dispatch, fetch per chunk
+    # one after another, never overlapping: assemble, dispatch, fetch, select
     assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))

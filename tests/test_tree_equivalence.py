@@ -506,6 +506,94 @@ def test_recommend_auto_routes_and_falls_back():
         recommend(pred, ctx, small, scorer="warp")
 
 
+# 14,400 candidates (the grid of test_recommend_auto_routes_and_falls_back)
+# and 9,000: neither a multiple of the gather block (8,192) nor of the
+# kernel's row block, so both modes score pad rows.
+_MEGA_GRIDS = {
+    "14400": dict(prefetch_policy=(0, 1), lookahead_batches=(4, 8),
+                  cache_budget_mb=(32.0, 64.0)),
+    "9000": dict(lookahead_batches=(2, 4, 8, 16, 32)),
+}
+
+
+def _interpret_pallas(monkeypatch):
+    """Run the packed scorer's Pallas kernel in the Pallas interpreter."""
+    import functools
+
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "gbt_predict_op",
+                        functools.partial(ops.gbt_predict_op, interpret=True))
+
+
+@pytest.mark.parametrize("grid", sorted(_MEGA_GRIDS))
+@pytest.mark.parametrize("mode", ["chunked", "pallas"])
+def test_score_grid_is_bit_identical_to_predict_ensemble(monkeypatch, mode, grid):
+    """The grid built and scored on the device gives every candidate the
+    float32 log score that ``predict_ensemble`` gives its row of the float32
+    feature matrix, bit for bit."""
+    from repro.core import ConfigSpace
+    from repro.core.autotune import score_grid
+    from repro.core.ensemble_base import predict_ensemble
+
+    if mode == "pallas":
+        _interpret_pallas(monkeypatch)
+    pred = _fitted_predictor("xgboost")
+    ctx = {"throughput_mb_s": 800.0, "file_size_mb": 64.0, "iops": 5e4}
+    space = ConfigSpace(**_MEGA_GRIDS[grid])
+    scores, got_mode = score_grid(pred, ctx, space, scorer=mode)
+    want = np.asarray(predict_ensemble(
+        pred.model.ensemble, space.feature_matrix(pred.spec, ctx).astype(np.float32)))
+    assert got_mode == mode
+    assert scores.dtype == np.float32 and scores.shape == (space.n_candidates,)
+    np.testing.assert_array_equal(scores.view(np.uint32), want.view(np.uint32))
+
+
+def test_score_grid_chunked_is_bit_identical_for_random_forest():
+    """The gather descent's blocks round ``base + scale * raw`` as
+    ``predict_ensemble`` does, with its multiply and add apart: a random
+    forest's 1/100 scale shows a fused multiply-add in the last bit."""
+    from repro.core import ConfigSpace
+    from repro.core.autotune import score_grid
+    from repro.core.ensemble_base import predict_ensemble
+
+    pred = _fitted_predictor("random_forest")
+    ctx = {"throughput_mb_s": 800.0, "file_size_mb": 64.0, "iops": 5e4}
+    space = ConfigSpace(**_MEGA_GRIDS["14400"])
+    scores, _ = score_grid(pred, ctx, space, scorer="chunked")
+    want = np.asarray(predict_ensemble(
+        pred.model.ensemble, space.feature_matrix(pred.spec, ctx).astype(np.float32)))
+    np.testing.assert_array_equal(scores.view(np.uint32), want.view(np.uint32))
+
+
+@pytest.mark.parametrize("mode", ["oracle", "chunked", "pallas"])
+def test_recommend_takes_ties_at_the_lowest_index(monkeypatch, mode):
+    """A model that reads one knob leaves thousands of candidates tied:
+    recommend() returns the k best scores, equal scores at the lowest
+    candidate index, the same picks on every call, each reported with the
+    oracle's own score of that candidate."""
+    from repro.core import FEATURE_NAMES, ConfigSpace, IOPerformancePredictor, recommend
+    from repro.core.autotune import score_grid
+
+    if mode == "pallas":
+        _interpret_pallas(monkeypatch)
+    rng = np.random.default_rng(3)
+    cols = {name: rng.uniform(1, 100, 240) for name in FEATURE_NAMES}
+    cols["batch_size"] = rng.choice([16, 32, 64, 128, 256], 240).astype(float)
+    cols["target_throughput"] = 100.0 + 50.0 * (cols["batch_size"] >= 64)
+    pred = IOPerformancePredictor(model="xgboost").fit(cols)
+    ctx = {"throughput_mb_s": 800.0, "file_size_mb": 64.0}
+    space = ConfigSpace(**_MEGA_GRIDS["14400"])
+    k = 7
+    oracle, _ = score_grid(pred, ctx, space, scorer="oracle")
+    best = np.lexsort((np.arange(oracle.size), -oracle))[:k]
+    assert np.sum(oracle == oracle[best[-1]]) > 1000  # the k-th place is a tie
+    first = recommend(pred, ctx, space, top_k=k, scorer=mode)
+    assert first == recommend(pred, ctx, space, top_k=k, scorer=mode)
+    assert _topk_key(first) == _topk_key([space.candidate(i) for i in best])
+    assert [r["predicted_throughput_mb_s"] for r in first] == oracle[best].tolist()
+
+
 def test_segment_sums_fast_matches_loop():
     from repro.core.tree import _segment_sums_fast, _segment_sums_loop
 
