@@ -10,6 +10,7 @@ Sources per assignment brackets:
   whisper-base    [arXiv:2212.04356]
   paligemma-3b    [arXiv:2407.07726]
   falcon-mamba-7b [arXiv:2410.05355]
+  granite-4.0-h-micro [hf:ibm-granite/granite-4.0-h-micro config.json]
 """
 
 from __future__ import annotations
@@ -106,17 +107,31 @@ FALCON_MAMBA_7B = ModelConfig(
     act="silu", attn_mode="seq_tp",
 )
 
+# Mamba-2 mixers beside NoPE GQA attention at layers 5, 15, 25, 35
+# (layer_types), a dense SwiGLU shared_mlp in every layer, no experts
+GRANITE_4_H_MICRO = ModelConfig(
+    name="granite-4.0-h-micro", family="hybrid",
+    n_layers=40, d_model=2048, n_heads=32, n_kv_heads=8,
+    head_dim=64, d_ff=8192, vocab_size=100352,
+    attn_period=10, attn_phase=5,
+    d_state=128, d_conv=4, expand=2,
+    ssm_version=2, ssm_heads=64, ssm_head_dim=64, ssm_groups=1, ssm_chunk=256,
+    use_rope=False, attn_logit_scale=0.015625, norm_eps=1e-5,
+    embed_multiplier=12.0, residual_multiplier=0.22, logits_divisor=8.0,
+    act="silu", gated_mlp=True, tie_embeddings=True, attn_mode=_attn_mode(32),
+)
+
 ARCHS = {
     c.name: c
     for c in (
         GRANITE_MOE_1B, GRANITE_MOE_3B, GRANITE_20B, GEMMA3_4B,
         DEEPSEEK_CODER_33B, CODEQWEN_7B, JAMBA_52B, WHISPER_BASE,
-        PALIGEMMA_3B, FALCON_MAMBA_7B,
+        PALIGEMMA_3B, FALCON_MAMBA_7B, GRANITE_4_H_MICRO,
     )
 }
 
 # Sub-quadratic archs that run long_500k (others skip; see DESIGN.md).
-LONG_CONTEXT_ARCHS = ("jamba-v0.1-52b", "falcon-mamba-7b", "gemma3-4b")
+LONG_CONTEXT_ARCHS = ("jamba-v0.1-52b", "falcon-mamba-7b", "gemma3-4b", "granite-4.0-h-micro")
 
 
 def shape_supported(cfg: ModelConfig, shape_name: str) -> tuple[bool, str]:
@@ -138,7 +153,11 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
             kw["n_kv_heads"] = 4
     if cfg.n_experts:
         kw.update(n_experts=4, top_k=2)
-    if cfg.family == "hybrid":
+    if cfg.family == "hybrid" and cfg.ssm_version == 2:
+        # a period of 4, attention at 2; 4 Mamba-2 heads of 16, chunks of 8
+        kw.update(n_layers=4, attn_period=4, attn_phase=2, d_state=8, d_conv=4,
+                  expand=1, ssm_heads=4, ssm_head_dim=16, ssm_chunk=8)
+    elif cfg.family == "hybrid":
         kw.update(n_layers=8, d_state=4, d_conv=4)
     if cfg.family == "ssm":
         kw.update(n_layers=2, d_state=4, d_conv=4)

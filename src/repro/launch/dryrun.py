@@ -105,10 +105,11 @@ def _points_and_weights(cfg: ModelConfig, kind: str):
             pts.append(({"n_layers": p + tail}, 1.0))
         return pts
     if cfg.family == "hybrid":
-        n_super = cfg.n_layers // 8
+        p = cfg.attn_period
+        n_super = cfg.n_layers // p
         return [
-            ({"n_layers": 8}, 1.0 - (n_super - 1)),
-            ({"n_layers": 16}, float(n_super - 1)),
+            ({"n_layers": p}, 1.0 - (n_super - 1)),
+            ({"n_layers": 2 * p}, float(n_super - 1)),
         ]
     L = cfg.n_layers
     return [({"n_layers": 1}, 2.0 - L), ({"n_layers": 2}, float(L - 1))]

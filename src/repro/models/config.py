@@ -42,14 +42,19 @@ class ModelConfig:
     rope_theta_global: float = 1_000_000.0
     attn_logit_scale: Optional[float] = None  # override 1/sqrt(head_dim)
 
-    # --- hybrid (jamba) ---
+    # --- hybrid (jamba, granite-4.0-h) ---
     attn_period: int = 0  # jamba: 8 -> one attention layer per 8
     attn_phase: int = 4
 
-    # --- ssm (mamba1) ---
+    # --- ssm (mamba1, mamba2) ---
     d_state: int = 0
     d_conv: int = 4
     expand: int = 2
+    ssm_version: int = 1  # 1: Mamba-1 selective scan; 2: Mamba-2 (SSD)
+    ssm_heads: int = 0  # Mamba-2: heads of ssm_head_dim channels, heads * head_dim = d_inner
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1  # Mamba-2: B/C groups shared by heads
+    ssm_chunk: int = 256  # Mamba-2: SSD chunk length
 
     # --- enc-dec (whisper) ---
     n_enc_layers: int = 0
@@ -65,6 +70,12 @@ class ModelConfig:
     embed_scale: bool = False  # gemma: x *= sqrt(d_model)
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+    use_rope: bool = True  # False: no position encoding (NoPE)
+    # granite multipliers: x0 = m_e * embed; x += m_r * branch; logits / d
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_divisor: float = 1.0
 
     # --- runtime / partitioning knobs (hillclimb levers) ---
     attn_mode: str = "heads_tp"  # heads_tp | seq_tp
@@ -119,6 +130,11 @@ class ModelConfig:
         return self.expand * self.d_model
 
     @property
+    def ssm_conv_dim(self) -> int:
+        """Mamba-2: channels through the conv (x, B and C)."""
+        return self.d_inner + 2 * self.ssm_groups * self.d_state
+
+    @property
     def dt_rank(self) -> int:
         return max(1, (self.d_model + 15) // 16)
 
@@ -153,6 +169,9 @@ class ModelConfig:
             total += 2 * D  # norms
             if self.is_attn_layer(i):
                 total += D * (H + 2 * KV) * hd + H * hd * D
+            elif self.family in ("ssm", "hybrid") and self.ssm_version == 2:
+                di, hs, cd = self.d_inner, self.ssm_heads, self.ssm_conv_dim
+                total += D * (di + cd + hs) + cd * (self.d_conv + 1) + 3 * hs + di + di * D
             elif self.family in ("ssm", "hybrid"):
                 di, ds, dr = self.d_inner, self.d_state, self.dt_rank
                 total += D * 2 * di + di * self.d_conv + di * (dr + 2 * ds) + dr * di + di + di * ds + di * D

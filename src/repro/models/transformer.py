@@ -137,8 +137,9 @@ def _project_qkv(cfg: ModelConfig, lp, x, positions, theta, ctx: ShardCtx):
     q = jnp.einsum("bsd,dhk->bshk", x, lp["attn"]["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, lp["attn"]["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, lp["attn"]["wv"])
-    q = rope(q, positions, theta)
-    k = rope(k, positions, theta)
+    if cfg.use_rope:
+        q = rope(q, positions, theta)
+        k = rope(k, positions, theta)
     if cfg.attn_mode == "seq_tp":
         # kv must be full-sequence (replicated) for the kv-chunk scan
         k = lc(k, ("batch", None, "kv_heads", None), ctx.rules)
@@ -155,7 +156,7 @@ def _attention_block(cfg: ModelConfig, lp, x, *, layer_global: bool,
     window = None if layer_global else cfg.window
     theta = cfg.rope_theta_global if (layer_global and cfg.local_global_period) else cfg.rope_theta
     positions = q_offset + jnp.arange(S, dtype=jnp.int32)
-    h = rms_norm(x, lp["ln1"], plus_one=cfg.rms_plus_one)
+    h = rms_norm(x, lp["ln1"], eps=cfg.norm_eps, plus_one=cfg.rms_plus_one)
     q, k, v = _project_qkv(cfg, lp, h, positions, theta, ctx)
     kw = dict(causal=True, window=window, prefix=prefix, q_offset=q_offset,
               rules=ctx.rules, scale=cfg.attn_logit_scale,
@@ -165,7 +166,14 @@ def _attention_block(cfg: ModelConfig, lp, x, *, layer_global: bool,
     else:
         o = attention_heads_tp(q, k, v, q_chunk=cfg.q_chunk, **kw)
     o = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
-    return x + lc(o, ("batch", "act_seq", "embed"), ctx.rules)
+    return x + residual(cfg, lc(o, ("batch", "act_seq", "embed"), ctx.rules))
+
+
+def residual(cfg: ModelConfig, y):
+    """A branch's output as the residual stream adds it."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
 
 
 def _moe_block_fn(cfg: ModelConfig, ctx: ShardCtx):
@@ -256,13 +264,13 @@ def _moe_block_fn(cfg: ModelConfig, ctx: ShardCtx):
 
 
 def _ffn_or_moe(cfg: ModelConfig, lp, x, is_moe: bool, ctx: ShardCtx):
-    h = rms_norm(x, lp["ln2"], plus_one=cfg.rms_plus_one)
+    h = rms_norm(x, lp["ln2"], eps=cfg.norm_eps, plus_one=cfg.rms_plus_one)
     if is_moe:
         y = _moe_block_fn(cfg, ctx)(h, lp["moe"])
     else:
         y = ffn(h, lp["ffn"]["w_in"], lp["ffn"].get("w_gate"), lp["ffn"]["w_out"],
                 act=cfg.act, rules=ctx.rules)
-    return x + y
+    return x + residual(cfg, y)
 
 
 def _layer(cfg: ModelConfig, lp, x, *, layer_global: bool, is_moe: bool,
@@ -291,6 +299,8 @@ def _embed(cfg: ModelConfig, params, tokens, ctx: ShardCtx,
         x = jnp.concatenate([prefix_embeds.astype(cfg.dtype), x], axis=1)
     if cfg.embed_scale:
         x = x * jnp.asarray(np.sqrt(cfg.d_model), cfg.dtype)
+    if cfg.embed_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embed_multiplier, cfg.dtype)
     return lc(x, ("batch", "act_seq", "embed"), ctx.rules)
 
 
@@ -389,11 +399,11 @@ def _decode_layer(cfg: ModelConfig, lp, cache_lp, x, pos, *, layer_global: bool,
     B = x.shape[0]
     window = None if layer_global else cfg.window
     theta = cfg.rope_theta_global if (layer_global and cfg.local_global_period) else cfg.rope_theta
-    h = rms_norm(x, lp["ln1"], plus_one=cfg.rms_plus_one)
+    h = rms_norm(x, lp["ln1"], eps=cfg.norm_eps, plus_one=cfg.rms_plus_one)
     positions = jnp.full((1,), pos, jnp.int32)
-    q = rope(jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"]), positions, theta)
-    k = rope(jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wk"]), positions, theta)
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wv"])
+    q, k, v = (jnp.einsum("bsd,dhk->bshk", h, lp["attn"][w]) for w in ("wq", "wk", "wv"))
+    if cfg.use_rope:
+        q, k = rope(q, positions, theta), rope(k, positions, theta)
     S = cache_lp["k"].shape[1]
     slot = pos % S if window is not None else pos  # ring buffer for local layers
     k_cache = jax.lax.dynamic_update_slice(cache_lp["k"], k, (0, slot, 0, 0))
@@ -403,7 +413,7 @@ def _decode_layer(cfg: ModelConfig, lp, cache_lp, x, pos, *, layer_global: bool,
                          window=None,  # ring buffer already bounds local layers
                          rules=ctx.rules, scale=cfg.attn_logit_scale)
     o = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
-    x = x + lc(o, ("batch", None, "embed"), ctx.rules)
+    x = x + residual(cfg, lc(o, ("batch", None, "embed"), ctx.rules))
     x = _ffn_or_moe(cfg, lp, x, is_moe, ctx)
     return x, {"k": k_cache, "v": v_cache}
 

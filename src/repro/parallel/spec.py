@@ -29,7 +29,7 @@ class ParamSpec:
     shape: Tuple[int, ...]
     axes: Tuple[Optional[str], ...]
     dtype: Any = jnp.bfloat16
-    init: str = "normal"  # normal | zeros | ones | mamba_a | conv
+    init: str = "normal"  # normal | zeros | ones | mamba_a | mamba2_a | mamba2_dt
     scale: float = 1.0
 
     def __post_init__(self):
@@ -132,9 +132,24 @@ def _init_one(key, s: ParamSpec):
         n = s.shape[-1]
         a = jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))
         return jnp.broadcast_to(a, s.shape).astype(s.dtype)
+    if s.init == "mamba2_a":
+        # Mamba-2 A_log: log of A ~ U[1, 16], one per head
+        return jnp.log(jax.random.uniform(key, s.shape, jnp.float32, 1.0, 16.0)).astype(s.dtype)
+    if s.init == "mamba2_dt":
+        # Mamba-2 dt_bias: softplus^-1(dt), dt log-uniform in [1e-3, 1e-1], floored at 1e-4
+        return _mamba2_dt_bias(key, s.shape).astype(s.dtype)
     fan_in = s.shape[0] if len(s.shape) >= 2 else max(s.shape[0], 1)
     std = s.scale / np.sqrt(fan_in)
     return (jax.random.normal(key, s.shape, jnp.float32) * std).astype(s.dtype)
+
+
+def _mamba2_dt_bias(key, shape):
+    """The Mamba-2 dt_bias initialiser: the inverse softplus of a dt drawn
+    log-uniformly from [1e-3, 1e-1] and floored at 1e-4."""
+    lo, hi = np.log(1e-3), np.log(1e-1)
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
+    dt = jnp.maximum(dt, 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def init_params(spec_tree, key):

@@ -18,6 +18,7 @@ EXPECTED_DIMS = {
     "whisper-base": (6, 512, 8, 8, 2048, 51865),
     "paligemma-3b": (18, 2048, 8, 1, 16384, 257216),
     "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
+    "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
 }
 
 
@@ -58,7 +59,7 @@ def test_param_counts_plausible():
 
 def test_40_cells_accounted():
     cells = list_cells()
-    assert len(cells) == 40
+    assert len(cells) == 44  # 11 archs x 4 shapes
     skips = [c for c in cells if not c["run"]]
     assert len(skips) == 7  # 7 archs skip long_500k
     assert all(c["shape"] == "long_500k" for c in skips)
@@ -68,6 +69,20 @@ def test_gemma3_local_global_pattern():
     c = get_config("gemma3-4b")
     globals_ = [i for i in range(c.n_layers) if c.is_global_layer(i)]
     assert globals_ == [5, 11, 17, 23, 29]  # every 6th of 34 layers
+
+
+def test_granite_h_micro_published_sizes():
+    c = get_config("granite-4.0-h-micro")
+    assert [i for i in range(c.n_layers) if c.is_attn_layer(i)] == [5, 15, 25, 35]
+    assert (c.ssm_version, c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.d_state,
+            c.d_conv, c.expand, c.ssm_chunk) == (2, 64, 64, 1, 128, 4, 2, 256)
+    assert c.ssm_conv_dim == 4352 and c.vocab_padded == c.vocab_size
+    assert (c.use_rope, c.attn_logit_scale, c.norm_eps) == (False, 1 / 64, 1e-5)
+    assert (c.embed_multiplier, c.residual_multiplier, c.logits_divisor) == (12, 0.22, 8)
+    assert c.n_experts == 0 and not any(c.is_moe_layer(i) for i in range(c.n_layers))
+    # 3.19 B parameters at 40 layers (ibm-granite/granite-4.0-h-micro)
+    assert abs(c.param_count() - 3.19e9) < 0.005 * 3.19e9
+    assert abs(c.replace(n_layers=10).param_count() - 951.7e6) < 1e6
 
 
 def test_jamba_attn_positions():
