@@ -5,7 +5,9 @@ params, activations) so it lowers identically under jit/pjit on any mesh.
 Attention TP modes
 ------------------
 - ``heads_tp``  (n_heads % tp == 0): q-chunked online-softmax scan; heads
-  sharded over "model". Memory per step: [B, qc, H_loc, S] scores.
+  sharded over "model". Memory per step: [B, qc, H_loc, S] scores. On a
+  TPU, a call that ``flash_applies`` to runs the Pallas flash kernel
+  instead, which keeps scores in VMEM and has its own backward.
 - ``seq_tp``    (small-head archs): q-sequence sharded over "model", kv
   replicated; kv-chunked online-softmax scan. Scores [B, S_loc, H, kc].
 
@@ -21,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.flash_attention import flash_attention
 from ..parallel.spec import Rules, logical_constraint as lc
 
 NEG_INF = -1e30
@@ -104,6 +107,9 @@ def attention_heads_tp(
     Sk, KVH = k.shape[1], k.shape[2]
     G = H // KVH
     scale = scale or D ** -0.5
+    if flash_applies(q.shape, k.shape, causal=causal, prefix=prefix,
+                     q_offset=q_offset, rules=rules):
+        return flash_attention(q, k, v, causal=True, window=window, scale=scale)
     qc = min(q_chunk, Sq)
     n_chunks = Sq // qc
     assert Sq % qc == 0, (Sq, qc)
@@ -140,6 +146,19 @@ def attention_heads_tp(
     )
     out = jnp.moveaxis(out, 0, 1).reshape(B, Sq, H, D)
     return lc(out, ("batch", None, "heads", None), rules)
+
+
+def flash_applies(q_shape, k_shape, *, causal: bool, prefix: Optional[int],
+                  q_offset, rules: Optional[Rules]) -> bool:
+    """Whether ``attention_heads_tp`` runs the Pallas flash kernel
+    (``kernels/flash_attention.py``) in place of its query-chunk scan: on a
+    TPU, for a call that no mesh shards, causal without a prefix, queries
+    aligned with the keys, rows a multiple of 128 and a head size of 64, 128
+    or 256."""
+    _, Sq, _, D = q_shape
+    return (jax.default_backend() == "tpu" and rules is None and causal
+            and prefix is None and isinstance(q_offset, int) and q_offset == 0
+            and Sq == k_shape[1] and Sq % 128 == 0 and D in (64, 128, 256))
 
 
 # --------------------------------------------------------------------------

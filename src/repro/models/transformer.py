@@ -275,8 +275,9 @@ def _ffn_or_moe(cfg: ModelConfig, lp, x, is_moe: bool, ctx: ShardCtx):
 
 def _layer(cfg: ModelConfig, lp, x, *, layer_global: bool, is_moe: bool,
            prefix: Optional[int], ctx: ShardCtx, q_offset: int = 0):
-    x = _attention_block(cfg, lp, x, layer_global=layer_global, prefix=prefix,
-                         ctx=ctx, q_offset=q_offset)
+    with jax.named_scope("repro.attention"):
+        x = _attention_block(cfg, lp, x, layer_global=layer_global, prefix=prefix,
+                             ctx=ctx, q_offset=q_offset)
     return _ffn_or_moe(cfg, lp, x, is_moe, ctx)
 
 

@@ -1,7 +1,8 @@
 """Ahead-of-time compiles of the main path's device programs for a described
 TPU v5e, at real widths: the Pallas mega-grid kernel, the whole-grid program
 that ``recommend()`` runs over 10^6 candidates, and the jitted gather
-descent that serves ``/predict`` and ``/recommend``.
+descent that serves ``/predict`` and ``/recommend``, and the training
+cells' flash attention with its backward.
 
 Nothing runs: the TPU compiler refuses here what the chip would refuse
 (tiling, fast-memory use, unsupported ops), so these tests guard the chip
@@ -21,6 +22,7 @@ from repro.core.ensemble_base import _predict_packed
 from repro.core.features import AUTOTUNE_FEATURE_NAMES
 from repro.core.predictor import make_model
 from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gbt_predict import gbt_predict
 from repro.service.serve import ServeConfig
 
@@ -117,3 +119,21 @@ def test_serving_descent_compiles_for_v5e(one_chip):
                              jnp.float32, sharding=one_chip)
     compiled = _predict_packed.lower(tables, x, max_depth=depth).compile()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("heads", [16, 32])
+def test_flash_attention_and_its_backward_compile_for_v5e(one_chip, heads):
+    """The training cells' attention (4 rows of 2048, 8 kv heads of 64; 16
+    query heads in granite-moe, 32 in the hybrid): the forward, dq and dkv
+    Pallas kernels at the blocks the rows give (512)."""
+    B, S, KV, D = 4, 2048, 8, 64
+
+    def s(h):
+        return jax.ShapeDtypeStruct((B, S, h, D), jnp.bfloat16, sharding=one_chip)
+
+    def step(q, k, v, w):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+        return o, vjp(w)
+
+    compiled = jax.jit(step).lower(s(heads), s(KV), s(KV), s(heads)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import block_for, flash_attention
 from repro.kernels.ops import gbt_predict_op
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ref import (
@@ -71,6 +71,65 @@ def test_flash_attention_mqa():
                            interpret=True)
     r = attention_reference(q, k, v, causal=True)
     assert float(jnp.max(jnp.abs(o - r))) < 2e-5
+
+
+@pytest.mark.parametrize("n, block", [(2048, 512), (768, 256), (384, 128), (64, 64)])
+def test_flash_attention_blocks_follow_the_rows(n, block):
+    assert block_for(n) == block
+
+
+def _grads(attend, q, k, v, w):
+    return jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * w), (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("head_dim, n_blocks", [(64, 2), (128, 3), (64, 4)])
+@pytest.mark.parametrize("groups", [1, 2, 4])
+@pytest.mark.parametrize("mask", [dict(causal=True), dict(causal=True, window=96)],
+                         ids=["causal", "window"])
+def test_flash_attention_gradients(mask, groups, head_dim, n_blocks):
+    """Output and the gradients of q, k and v against the dense reference and
+    the scan path's ``jax.grad``, with blocks skipped past the diagonal and
+    before the window."""
+    from repro.models.common import attention_heads_tp
+
+    KV, S = 2, 64 * n_blocks
+    q, k, v = _qkv(jax.random.PRNGKey(groups), 1, S, KV * groups, KV, head_dim, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(11), q.shape)
+    o = flash_attention(q, k, v, q_block=64, kv_block=64, interpret=True, **mask)
+    assert float(jnp.max(jnp.abs(o - attention_reference(q, k, v, **mask)))) < 2e-5
+    flash = _grads(lambda q, k, v: flash_attention(
+        q, k, v, q_block=64, kv_block=64, interpret=True, **mask), q, k, v, w)
+    dense = _grads(lambda q, k, v: attention_reference(q, k, v, **mask), q, k, v, w)
+    scan = _grads(lambda q, k, v: attention_heads_tp(q, k, v, q_chunk=64, **mask), q, k, v, w)
+    for other in (dense, scan):
+        for a, b in zip(flash, other):
+            assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
+@pytest.mark.parametrize("q_block, kv_block", [(64, 128), (128, 64)])
+def test_flash_attention_gradients_with_unequal_blocks(q_block, kv_block):
+    q, k, v = _qkv(jax.random.PRNGKey(5), 2, 256, 4, 2, 64, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(12), q.shape)
+    mask = dict(causal=True, window=96)
+    flash = _grads(lambda q, k, v: flash_attention(
+        q, k, v, q_block=q_block, kv_block=kv_block, interpret=True, **mask), q, k, v, w)
+    dense = _grads(lambda q, k, v: attention_reference(q, k, v, **mask), q, k, v, w)
+    for a, b in zip(flash, dense):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
+def test_flash_attention_gradients_bf16():
+    """bfloat16 operands into the MXU, float32 softmax: gradients within
+    bfloat16's rounding of the float32 reference's."""
+    q, k, v = _qkv(jax.random.PRNGKey(6), 1, 128, 4, 2, 64, jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(13), q.shape, jnp.bfloat16)
+    flash = _grads(lambda q, k, v: flash_attention(
+        q, k, v, q_block=64, kv_block=64, interpret=True), q, k, v, w)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, w)]
+    dense = _grads(lambda q, k, v: attention_reference(q, k, v), *f32)
+    for a, b in zip(flash, dense):
+        a = a.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(a - b))) < 2e-2 * float(jnp.max(jnp.abs(b)))
 
 
 # ---------------------------------------------------------------- rmsnorm
