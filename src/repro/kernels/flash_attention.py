@@ -10,7 +10,8 @@ Design (TPU-native, not a CUDA port):
   online-softmax state (m, l, acc) lives in VMEM scratch and persists across
   kv steps. Scores and probabilities never reach HBM; the kernel writes o
   and the float32 logsumexp of each row.
-- Backward: ``di = rowsum(dO * O)`` is computed beforehand. The dq kernel
+- Backward, for the causal mask without a prefix (the mask training
+  takes): ``di = rowsum(dO * O)`` is computed beforehand. The dq kernel
   (grid (B*H, n_q, n_kv)) and the dkv kernel (grid (B*KVH, n_kv, G, n_q))
   recompute p from q, k and the saved logsumexp; the dkv kernel sums over
   the G query heads of each kv head in VMEM, and works on transposed scores
@@ -77,10 +78,9 @@ def _kv_blocks(qi, p: _Plan, n_kv: int):
 
 
 def _q_blocks(ki, p: _Plan, n_q: int):
-    """First and last query block that attends to kv block ``ki``."""
-    lo, hi = 0, n_q - 1
-    if p.causal and p.prefix is None:
-        lo = (ki * p.kv_block) // p.q_block
+    """First and last query block that attends to kv block ``ki``, under the
+    causal mask without a prefix that the backward serves."""
+    lo, hi = (ki * p.kv_block) // p.q_block, n_q - 1
     if p.window is not None:
         hi = jnp.minimum(hi, ((ki + 1) * p.kv_block + p.window - 2) // p.q_block)
     return lo, hi
@@ -380,8 +380,9 @@ def flash_attention(
 ):
     """q: [B, Sq, H, D]; k/v: [B, Skv, KVH, D] -> [B, Sq, H, D].
 
-    Differentiable in q, k and v. GQA: query head h reads kv head h // G.
-    Blocks default to ``block_for`` of each sequence length.
+    Differentiable in q, k and v where causal without a prefix; other masks
+    run the forward alone. GQA: query head h reads kv head h // G. Blocks
+    default to ``block_for`` of each sequence length.
     """
     B, Sq, H, D = q.shape
     Skv, KVH = k.shape[1], k.shape[2]
@@ -398,5 +399,8 @@ def flash_attention(
         return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], D)
 
     with jax.named_scope(SCOPE):
-        o = _attend(heads(q), heads(k), heads(v), p)
+        if causal and prefix is None:
+            o = _attend(heads(q), heads(k), heads(v), p)
+        else:  # the forward alone
+            o = _forward(heads(q), heads(k), heads(v), p)[0]
         return o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

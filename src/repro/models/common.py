@@ -5,8 +5,8 @@ params, activations) so it lowers identically under jit/pjit on any mesh.
 Attention TP modes
 ------------------
 - ``heads_tp``  (n_heads % tp == 0): q-chunked online-softmax scan; heads
-  sharded over "model". Memory per step: [B, qc, H_loc, S] scores. On a
-  TPU, a call that ``flash_applies`` to runs the Pallas flash kernel
+  sharded over "model". Memory per step: [B, qc, H_loc, S] scores. Lowered
+  for a TPU, a call that ``flash_applies`` to runs the Pallas flash kernel
   instead, which keeps scores in VMEM and has its own backward.
 - ``seq_tp``    (small-head archs): q-sequence sharded over "model", kv
   replicated; kv-chunked online-softmax scan. Scores [B, S_loc, H, kc].
@@ -17,6 +17,7 @@ and masked for causal / sliding-window / prefix-LM.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -102,14 +103,29 @@ def attention_heads_tp(
     unroll: bool = False,
     probs_bf16: bool = False,
 ):
-    """q: [B, Sq, H, D]; k/v: [B, Sk, KVH, D] -> [B, Sq, H, D]."""
+    """q: [B, Sq, H, D]; k/v: [B, Sk, KVH, D] -> [B, Sq, H, D].
+
+    Lowered for a TPU, a call that ``flash_applies`` to runs the Pallas flash
+    kernel; every other call, and every lowering for another platform, runs
+    the query-chunk scan."""
+    scale = scale or q.shape[-1] ** -0.5
+    scan = functools.partial(
+        _heads_tp_scan, causal=causal, window=window, prefix=prefix,
+        q_offset=q_offset, q_chunk=q_chunk, rules=rules, scale=scale,
+        unroll=unroll, probs_bf16=probs_bf16)
+    if not flash_applies(q.shape, k.shape, causal=causal, prefix=prefix,
+                         q_offset=q_offset, rules=rules):
+        return scan(q, k, v)
+    flash = functools.partial(flash_attention, causal=True, window=window, scale=scale)
+    return jax.lax.platform_dependent(q, k, v, tpu=flash, default=scan)
+
+
+def _heads_tp_scan(q, k, v, *, causal, window, prefix, q_offset, q_chunk, rules,
+                   scale, unroll, probs_bf16):
+    """``attention_heads_tp``'s query-chunk scan."""
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
     G = H // KVH
-    scale = scale or D ** -0.5
-    if flash_applies(q.shape, k.shape, causal=causal, prefix=prefix,
-                     q_offset=q_offset, rules=rules):
-        return flash_attention(q, k, v, causal=True, window=window, scale=scale)
     qc = min(q_chunk, Sq)
     n_chunks = Sq // qc
     assert Sq % qc == 0, (Sq, qc)
@@ -150,14 +166,13 @@ def attention_heads_tp(
 
 def flash_applies(q_shape, k_shape, *, causal: bool, prefix: Optional[int],
                   q_offset, rules: Optional[Rules]) -> bool:
-    """Whether ``attention_heads_tp`` runs the Pallas flash kernel
-    (``kernels/flash_attention.py``) in place of its query-chunk scan: on a
-    TPU, for a call that no mesh shards, causal without a prefix, queries
-    aligned with the keys, rows a multiple of 128 and a head size of 64, 128
-    or 256."""
+    """Whether the Pallas flash kernel (``kernels/flash_attention.py``)
+    serves an ``attention_heads_tp`` call where it is lowered for a TPU: a
+    call that no mesh shards, causal without a prefix, queries aligned with
+    the keys, rows a multiple of 128 and a head size of 64, 128 or 256."""
     _, Sq, _, D = q_shape
-    return (jax.default_backend() == "tpu" and rules is None and causal
-            and prefix is None and isinstance(q_offset, int) and q_offset == 0
+    return (rules is None and causal and prefix is None
+            and isinstance(q_offset, int) and q_offset == 0
             and Sq == k_shape[1] and Sq % 128 == 0 and D in (64, 128, 256))
 
 

@@ -1,8 +1,9 @@
 """Ahead-of-time compiles of the main path's device programs for a described
 TPU v5e, at real widths: the Pallas mega-grid kernel, the whole-grid program
 that ``recommend()`` runs over 10^6 candidates, and the jitted gather
-descent that serves ``/predict`` and ``/recommend``, and the training
-cells' flash attention with its backward.
+descent that serves ``/predict`` and ``/recommend``, the flash attention
+kernel with its backward at every head size its dispatch takes, and the
+training cells' whole step, which reaches that kernel.
 
 Nothing runs: the TPU compiler refuses here what the chip would refuse
 (tiling, fast-memory use, unsupported ops), so these tests guard the chip
@@ -22,6 +23,7 @@ from repro.core.ensemble_base import _predict_packed
 from repro.core.features import AUTOTUNE_FEATURE_NAMES
 from repro.core.predictor import make_model
 from repro.kernels import ops
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gbt_predict import gbt_predict
 from repro.service.serve import ServeConfig
@@ -137,3 +139,51 @@ def test_flash_attention_and_its_backward_compile_for_v5e(one_chip, heads):
 
     compiled = jax.jit(step).lower(s(heads), s(KV), s(KV), s(heads)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("heads, kv, head_dim, window", [
+    (8, 4, 128, None), (8, 4, 256, None), (16, 8, 64, 512), (8, 4, 256, 1024)])
+def test_flash_attention_compiles_for_v5e_at_other_head_sizes_and_windows(
+        one_chip, heads, kv, head_dim, window):
+    """The other head sizes that ``models.common.flash_applies`` takes, and
+    sliding windows (gemma3's local layers: 1024 rows, heads of 256)."""
+    B, S = 2, 2048
+
+    def s(h):
+        return jax.ShapeDtypeStruct((B, S, h, head_dim), jnp.bfloat16, sharding=one_chip)
+
+    def step(q, k, v, w):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window), q, k, v)
+        return o, vjp(w)
+
+    compiled = jax.jit(step).lower(s(heads), s(kv), s(kv), s(heads)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("name, layers", [
+    ("granite-moe-1b-a400m", dict(n_layers=1)),
+    ("granite-4.0-h-micro", dict(n_layers=2, attn_period=2, attn_phase=1))],
+    ids=["granite-moe", "hybrid"])
+def test_training_step_compiles_for_v5e_through_the_flash_kernel(one_chip, tmp_path,
+                                                                   name, layers):
+    """``Trainer._step`` at a training cell's widths and rows (4 x 2048),
+    cut to one attention layer, lowered for a v5e: its Pallas calls are the
+    kernel's forward (the remat runs it again), dq and dkv, each under the
+    scope ``repro.attention.flash``."""
+    import types
+
+    from repro.train.trainer import Trainer, TrainerConfig
+
+    cfg = get_config(name).replace(**layers)
+    pipe = types.SimpleNamespace(config=types.SimpleNamespace(batch_size=4))
+    trainer = Trainer(cfg, pipe, TrainerConfig(ckpt_dir=str(tmp_path)))
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    state = jax.tree.map(on_chip, jax.eval_shape(trainer.init_state))
+    rows = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
+    text = trainer._step.lower(state, {"tokens": rows, "labels": rows}).compile().as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4 and all("repro.attention.flash" in line for line in calls)
