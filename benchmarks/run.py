@@ -1,5 +1,5 @@
-"""Benchmark harness — one function per paper table/figure plus the dry-run
-roofline table. Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one function per paper table/figure. Prints
+``name,us_per_call,derived`` CSV.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--fast] [--only GROUP]
        [--artifact-dir DIR]
@@ -36,7 +36,6 @@ def main(argv=None) -> None:
     from . import loop_bench
     from . import paper_experiments as pe
     from . import pipeline_bench
-    from . import roofline
     from . import serve_bench
     from . import transfer_bench
 
@@ -59,12 +58,9 @@ def main(argv=None) -> None:
         "kernels": pe.bench_kernels,
     }
     if args.only:
-        if args.only not in groups and args.only != "roofline":
-            ap.error(
-                f"unknown benchmark group {args.only!r}; "
-                f"choose from {sorted(groups) + ['roofline']}"
-            )
-        groups = {args.only: groups[args.only]} if args.only in groups else {}
+        if args.only not in groups:
+            ap.error(f"unknown benchmark group {args.only!r}; choose from {sorted(groups)}")
+        groups = {args.only: groups[args.only]}
 
     print("name,us_per_call,derived")
     failures = 0
@@ -82,17 +78,6 @@ def main(argv=None) -> None:
             failures += 1
             print(f"{gname},0,ERROR {type(e).__name__}: {e}", file=sys.stdout)
             traceback.print_exc(file=sys.stderr)
-
-    # roofline rows from the dry-run artifacts (if present)
-    if args.only in (None, "roofline"):
-        try:
-            recs = roofline.load_records()
-            for name, us, derived in roofline.csv_rows(recs):
-                print(f"{name},{us:.1f},{derived}")
-            s = roofline.summarize(recs)
-            print(f"roofline_summary,0,{s}")
-        except Exception as e:  # noqa: BLE001
-            print(f"roofline,0,ERROR {e}")
 
     if failures:
         sys.exit(1)

@@ -1,9 +1,7 @@
 """End-to-end training driver: real on-disk dataset -> tunable pipeline ->
 fault-tolerant trainer (checkpoints + autotune) for any LM-family arch.
 
-This drives a few hundred steps of a reduced-config model on CPU; on a pod,
-the same Trainer wraps the pjit train step from repro.train.step (see
-repro/launch/dryrun.py for the production-mesh lowering of every arch).
+This drives a few hundred steps of a reduced-config model on CPU.
 
 Run: PYTHONPATH=src python examples/train_lm.py [--arch codeqwen1.5-7b]
      PYTHONPATH=src python examples/train_lm.py --arch falcon-mamba-7b --steps 50

@@ -1,6 +1,3 @@
-"""repro.launch — mesh construction, dry-run, train/serve entry points.
+"""repro.launch — mesh construction, train/serve entry points."""
 
-NOTE: do not import .dryrun from here — it sets XLA_FLAGS at import time.
-"""
-
-from .mesh import make_production_mesh, make_test_mesh  # noqa: F401
+from .mesh import make_test_mesh  # noqa: F401

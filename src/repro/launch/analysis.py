@@ -14,7 +14,7 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-__all__ = ["HW", "CollectiveOp", "parse_collectives", "roofline_terms", "summarize_collectives"]
+__all__ = ["HW", "CollectiveOp", "parse_collectives", "roofline_terms"]
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -102,16 +102,6 @@ def parse_collectives(hlo_text: str, default_group: int = 1) -> List[CollectiveO
         ops.append(CollectiveOp(op, out_bytes, g, _wire_bytes(op, out_bytes, g),
                                 line.strip()[:160]))
     return ops
-
-
-def summarize_collectives(ops: List[CollectiveOp]) -> Dict[str, Dict[str, float]]:
-    out: Dict[str, Dict[str, float]] = {}
-    for o in ops:
-        d = out.setdefault(o.op, {"count": 0, "out_bytes": 0.0, "wire_bytes": 0.0})
-        d["count"] += 1
-        d["out_bytes"] += o.out_bytes
-        d["wire_bytes"] += o.wire_bytes
-    return out
 
 
 def roofline_terms(

@@ -1,9 +1,8 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as a FUNCTION so importing this module never touches jax device
-state. Single pod: 16x16 = 256 chips ("data", "model"). Multi-pod: 2 pods x
-256 = 512 chips with a leading "pod" axis carrying only data-parallel
-gradient traffic (matching slow inter-pod links).
+state. Axes ("data", "model"), with a leading "pod" axis for multi-pod
+meshes that carries only data-parallel gradient traffic.
 
 Mesh axes are ``Auto``: the model code places activations with
 ``with_sharding_constraint`` by logical axis (``parallel/spec.py``) and leaves
@@ -15,13 +14,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_test_mesh"]
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
+__all__ = ["make_test_mesh"]
 
 
 def _auto_mesh(shape, axes):

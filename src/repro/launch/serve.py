@@ -1,6 +1,6 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id>`` runs the
-batched engine on a reduced config (CPU demo); the full-size decode path is
-exercised on the production mesh by ``repro.launch.dryrun`` (decode cells)."""
+batched engine on a reduced config (CPU demo); the meshed decode step
+(``train.step.make_serve_bundle``) compiles in ``tests/test_sharding_meshes.py``."""
 
 from __future__ import annotations
 

@@ -1,9 +1,7 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Local mode (default) runs a reduced config end-to-end on this host with the
-real data pipeline + autotuner + checkpointing. ``--dry-mesh`` instead lowers
-the full-size pjit train step on the production mesh (see dryrun.py for the
-batch sweep version).
+Runs a reduced config (``--full`` for published widths) end-to-end on this
+host with the real data pipeline + autotuner + checkpointing.
 """
 
 from __future__ import annotations

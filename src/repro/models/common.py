@@ -100,8 +100,6 @@ def attention_heads_tp(
     q_chunk: int = 512,
     rules: Optional[Rules] = None,
     scale: Optional[float] = None,
-    unroll: bool = False,
-    probs_bf16: bool = False,
 ):
     """q: [B, Sq, H, D]; k/v: [B, Sk, KVH, D] -> [B, Sq, H, D].
 
@@ -111,8 +109,7 @@ def attention_heads_tp(
     scale = scale or q.shape[-1] ** -0.5
     scan = functools.partial(
         _heads_tp_scan, causal=causal, window=window, prefix=prefix,
-        q_offset=q_offset, q_chunk=q_chunk, rules=rules, scale=scale,
-        unroll=unroll, probs_bf16=probs_bf16)
+        q_offset=q_offset, q_chunk=q_chunk, rules=rules, scale=scale)
     if not flash_applies(q.shape, k.shape, causal=causal, prefix=prefix,
                          q_offset=q_offset, rules=rules):
         return scan(q, k, v)
@@ -121,7 +118,7 @@ def attention_heads_tp(
 
 
 def _heads_tp_scan(q, k, v, *, causal, window, prefix, q_offset, q_chunk, rules,
-                   scale, unroll, probs_bf16):
+                   scale):
     """``attention_heads_tp``'s query-chunk scan."""
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
@@ -149,17 +146,11 @@ def _heads_tp_scan(q, k, v, *, causal, window, prefix, q_offset, q_chunk, rules,
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
         p = p / jnp.maximum(l, 1e-30)
-        if probs_bf16:
-            p = p.astype(jnp.bfloat16)
-            o = jnp.einsum("bhgqs,bshd->bqhgd", p, v.astype(jnp.bfloat16))
-        else:
-            o = jnp.einsum("bhgqs,bshd->bqhgd", p, v.astype(jnp.float32))
+        o = jnp.einsum("bhgqs,bshd->bqhgd", p, v.astype(jnp.float32))
         return carry, o.astype(q.dtype)
 
     _, out = jax.lax.scan(
-        chunk_body, None, (jnp.arange(n_chunks), jnp.moveaxis(q, 1, 0)),
-        unroll=True if unroll else 1,
-    )
+        chunk_body, None, (jnp.arange(n_chunks), jnp.moveaxis(q, 1, 0)))
     out = jnp.moveaxis(out, 0, 1).reshape(B, Sq, H, D)
     return lc(out, ("batch", None, "heads", None), rules)
 
@@ -188,8 +179,6 @@ def attention_seq_tp(
     kv_chunk: int = 1024,
     rules: Optional[Rules] = None,
     scale: Optional[float] = None,
-    unroll: bool = False,
-    probs_bf16: bool = False,
 ):
     """Online-softmax over kv chunks; q seq dim stays sharded ("act_seq")."""
     B, Sq, H, D = q.shape
@@ -218,21 +207,15 @@ def attention_seq_tp(
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
-        pv = p.astype(jnp.bfloat16) if probs_bf16 else p
         acc_new = acc * corr[..., None] + jnp.einsum(
-            "bqhgs,bshd->bqhgd", pv,
-            vi.astype(jnp.bfloat16 if probs_bf16 else jnp.float32),
-        ).astype(jnp.float32)
+            "bqhgs,bshd->bqhgd", p, vi.astype(jnp.float32))
         carry = (m_new, l_new, lc(acc_new, ("batch", "act_seq", "kv_heads", "heads", None), rules))
         return carry, None
 
     m0 = jnp.full((B, Sq, KVH, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Sq, KVH, G), jnp.float32)
     a0 = jnp.zeros((B, Sq, KVH, G, D), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0), (jnp.arange(n_chunks), k_r, v_r),
-        unroll=True if unroll else 1,
-    )
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (jnp.arange(n_chunks), k_r, v_r))
     out = (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(B, Sq, H, D).astype(q.dtype)
     return lc(out, ("batch", "act_seq", "heads", None), rules)
 
@@ -297,18 +280,12 @@ def _activate(x, act: str):
 # expert-parallel over "model" via replicated-activation + psum combine.
 # --------------------------------------------------------------------------
 def moe_dispatch(x2d, router_w, *, n_experts: int, top_k: int,
-                 capacity_factor: float = 1.25, renormalize: bool = True,
-                 expert_lo=None, n_local: Optional[int] = None):
+                 capacity_factor: float = 1.25, renormalize: bool = True):
     """Top-k routing + sort-based capacity dispatch. x2d: [T, D].
 
-    Returns (xe [E_out, C, D], dispatch_meta, C). ``n_experts`` may exceed the
+    Returns (xe [E, C, D], dispatch_meta, C). ``n_experts`` may exceed the
     router's width (padded experts receive no traffic — the top_k indices only
     span router_w.shape[1] real experts).
-
-    When ``expert_lo``/``n_local`` are given (local-dispatch optimization),
-    only the shard's expert range [lo, lo+n_local) is materialized — the
-    buffer is [n_local, C, D] and assignments outside the range are masked,
-    cutting dispatch HBM traffic by the EP degree.
     """
     T, D = x2d.shape
     n_real = router_w.shape[1]
@@ -332,20 +309,12 @@ def moe_dispatch(x2d, router_w, *, n_experts: int, top_k: int,
 
     C = max(1, int(np.ceil(T * K / max(n_real, 1) * capacity_factor)))
     keep = pos_in_e < C
-    if expert_lo is not None and n_local is not None:
-        lo = jnp.asarray(expert_lo, jnp.int32)
-        local = (se >= lo) & (se < lo + n_local)
-        keep = keep & local
-        e_out = n_local
-        slot = jnp.where(keep, (se - lo) * C + pos_in_e, e_out * C)
-    else:
-        e_out = n_experts
-        slot = jnp.where(keep, se * C + pos_in_e, e_out * C)  # overflow -> dropped
+    slot = jnp.where(keep, se * C + pos_in_e, n_experts * C)  # overflow -> dropped
 
-    # dispatch: buffer [E_out*C(+1), D]
-    buf = jnp.zeros((e_out * C + 1, D), x2d.dtype)
+    # dispatch: buffer [E*C(+1), D]
+    buf = jnp.zeros((n_experts * C + 1, D), x2d.dtype)
     buf = buf.at[slot].set(jnp.where(keep[:, None], x2d[st], 0), mode="drop")
-    xe = buf[: e_out * C].reshape(e_out, C, D)
+    xe = buf[: n_experts * C].reshape(n_experts, C, D)
 
     return xe, (slot, st, sg, keep), C
 
@@ -375,8 +344,7 @@ def moe_combine(out_e_all, dispatch_meta, T: int, D: int, n_experts: int, C: int
 # Chunked softmax cross-entropy (big-vocab safe)
 # --------------------------------------------------------------------------
 def chunked_cross_entropy(x2d, unembed, labels, *, chunk: int = 4096,
-                          rules: Optional[Rules] = None, z_loss: float = 0.0,
-                          unroll: bool = False):
+                          rules: Optional[Rules] = None, z_loss: float = 0.0):
     """x2d: [T, D] hidden; unembed: [D, V]; labels: [T] int32. Mean NLL.
 
     Scans token chunks so the [chunk, V] logits tensor never materializes for
@@ -404,6 +372,5 @@ def chunked_cross_entropy(x2d, unembed, labels, *, chunk: int = 4096,
             nll = nll + z_loss * (lse ** 2).sum()
         return tot + nll, None
 
-    tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs,
-                          unroll=True if unroll else 1)
+    tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
     return tot / T

@@ -1,4 +1,4 @@
-"""Unified architecture config covering all 10 assigned archs."""
+"""One config dataclass for every architecture that ``configs/archs.py`` registers."""
 
 from __future__ import annotations
 
@@ -77,40 +77,13 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     logits_divisor: float = 1.0
 
-    # --- runtime / partitioning knobs (hillclimb levers) ---
+    # --- runtime / partitioning ---
     attn_mode: str = "heads_tp"  # heads_tp | seq_tp
     q_chunk: int = 256
     kv_chunk: int = 1024
     xent_chunk: int = 2048
     ssm_scan_chunk: int = 64
     remat: bool = True
-    capacity_factor_decode: float = 2.0
-    # Cost-measurement mode (dry-run roofline extraction): fully unroll every
-    # lax.scan so XLA cost_analysis (which counts while bodies ONCE) sees the
-    # true op counts. Never used for real execution.
-    unroll_scans: bool = False
-
-    # --- §Perf hillclimb levers (beyond-paper optimizations) ---
-    # MoE: build dispatch buffers only for the shard's local experts
-    # ([E_loc, C, D] instead of [E, C, D]) — 16x less dispatch HBM traffic.
-    moe_local_dispatch: bool = False
-    # SSM: compute the scan gates (a, b) per chunk inside the outer scan
-    # instead of materializing full-sequence [B, S, di, ds] tensors.
-    ssm_chunk_local: bool = False
-    # SSM: dtype for the (a, bx) gate tensors — bf16 halves the dominant HBM
-    # traffic of the reference scan; carries stay f32.
-    ssm_gate_dtype: Any = jnp.float32
-    # MoE: replicate expert weights instead of EP (kills the per-layer psum;
-    # pays expert-weight HBM; wins when experts are small, e.g. granite).
-    moe_replicate_experts: bool = False
-    # Attention: cast softmax probabilities to bf16 before the p@V matmul
-    # (what real flash kernels feed the MXU) — halves the dominant f32
-    # score/prob HBM traffic of the reference lowering.
-    attn_probs_bf16: bool = False
-    # Remat policy: "full" recomputes the whole layer in backward;
-    # "dots" saves matmul outputs (jax.checkpoint_policies) — ~25% less
-    # backward compute at the cost of saved activations.
-    remat_policy: str = "full"
 
     # ------------------------------------------------------------------
     @property

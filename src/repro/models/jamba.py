@@ -111,7 +111,7 @@ def _backbone(cfg: ModelConfig, params, x, ctx: ShardCtx, train: bool = False):
 
     if train and cfg.remat and cfg.ssm_version != 2:
         body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["blocks"], unroll=True if cfg.unroll_scans else 1)
+    x, _ = jax.lax.scan(body, x, params["blocks"])
     return _head_input(cfg, rms_norm(x, params["final_norm"], eps=cfg.norm_eps))
 
 
@@ -129,7 +129,7 @@ def loss_fn(cfg: ModelConfig, params, batch, ctx: ShardCtx = LOCAL_CTX):
     return chunked_cross_entropy(
         x.reshape(B * S, D), _unembed_weight(cfg, params),
         batch["labels"].reshape(B * S), chunk=min(cfg.xent_chunk, B * S),
-        rules=ctx.rules, unroll=cfg.unroll_scans,
+        rules=ctx.rules,
     )
 
 
@@ -182,8 +182,7 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos, ctx: ShardCtx = LOC
             x = _ffn_or_moe(cfg, lp, x, is_moe, ctx)
         return x, new_c
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]),
-                                unroll=True if cfg.unroll_scans else 1)
+    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
     x = _head_input(cfg, rms_norm(x, params["final_norm"], eps=cfg.norm_eps))
     logits = jnp.einsum("bsd,dv->bsv", x, _unembed_weight(cfg, params))
     logits = lc(logits, ("batch", None, "vocab"), ctx.rules)

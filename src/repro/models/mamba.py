@@ -63,7 +63,7 @@ def _causal_conv(x, w, b, ctx: ShardCtx):
     return acc + b
 
 
-def _ssm_scan_chunked(a, b, h0, chunk: int, unroll: bool = False):
+def _ssm_scan_chunked(a, b, h0, chunk: int):
     """h_t = a_t*h_{t-1} + b_t over seq axis 1. a,b: [B, S, di, ds].
     Outer scan over chunks, associative scan within."""
     B, S, di, ds = a.shape
@@ -84,50 +84,9 @@ def _ssm_scan_chunked(a, b, h0, chunk: int, unroll: bool = False):
         h_all = B_cum + A_cum * h[:, None]
         return h_all[:, -1], h_all
 
-    h_end, ys = jax.lax.scan(outer, h0, (a_r, b_r), unroll=True if unroll else 1)
+    h_end, ys = jax.lax.scan(outer, h0, (a_r, b_r))
     ys = jnp.moveaxis(ys, 0, 1).reshape(B, S, di, ds)
     return ys, h_end
-
-
-def _ssm_chunk_local(cfg: ModelConfig, lp, xc, ctx: ShardCtx):
-    """§Perf lever (ssm_chunk_local): compute gates (dt, B, C, a, bx) PER
-    CHUNK inside the scan instead of materializing full-sequence
-    [B, S, di, ds] tensors — the reference path's dominant HBM traffic.
-    xc: [B, S, di] (post-conv, activated). Returns y [B, S, di] f32."""
-    B, S, di = xc.shape
-    ds, dr = cfg.d_state, cfg.dt_rank
-    c = min(cfg.ssm_scan_chunk, S)
-    while S % c:
-        c -= 1
-    n = S // c
-    xc_r = jnp.moveaxis(xc.reshape(B, n, c, di), 1, 0)
-    A = -jnp.exp(lp["a_log"])  # [di, ds]
-
-    def combine(l, r):
-        al, bl = l
-        ar, br = r
-        return al * ar, ar * bl + br
-
-    def outer(h, xc_c):
-        bcdt = jnp.einsum("bse,ef->bsf", xc_c, lp["w_bcdt"]).astype(jnp.float32)
-        dt_in, Bmat, Cmat = jnp.split(bcdt, [dr, dr + ds], axis=-1)
-        dt = jax.nn.softplus(
-            jnp.einsum("bsr,re->bse", dt_in.astype(xc_c.dtype), lp["w_dt"]).astype(jnp.float32)
-            + lp["b_dt"]
-        )
-        gd = cfg.ssm_gate_dtype
-        a = jnp.exp(dt[..., None] * A).astype(gd)
-        bx = ((dt * xc_c.astype(jnp.float32))[..., None]
-              * Bmat[:, :, None, :]).astype(gd)
-        A_cum, B_cum = jax.lax.associative_scan(combine, (a, bx), axis=1)
-        h_all = B_cum.astype(jnp.float32) + A_cum.astype(jnp.float32) * h[:, None]
-        y_c = (h_all * Cmat[:, :, None, :]).sum(-1)  # [B, c, di]
-        y_c = y_c + lp["d_skip"] * xc_c.astype(jnp.float32)
-        return h_all[:, -1], y_c
-
-    h0 = jnp.zeros((B, di, ds), jnp.float32)
-    _, ys = jax.lax.scan(outer, h0, xc_r, unroll=True if cfg.unroll_scans else 1)
-    return jnp.moveaxis(ys, 0, 1).reshape(B, S, di)
 
 
 def mamba_mixer(cfg: ModelConfig, lp, x, ctx: ShardCtx, h0=None, conv_state=None):
@@ -153,12 +112,6 @@ def mamba_mixer(cfg: ModelConfig, lp, x, ctx: ShardCtx, h0=None, conv_state=None
         new_conv_state = None
     xc = jax.nn.silu(xc.astype(jnp.float32)).astype(x.dtype)
 
-    if cfg.ssm_chunk_local and conv_state is None and S > 1:
-        y = _ssm_chunk_local(cfg, lp, xc, ctx)
-        y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-        out = jnp.einsum("bse,ed->bsd", y, lp["w_out"])
-        return lc(out, ("batch", "act_seq", "embed"), ctx.rules)
-
     bcdt = jnp.einsum("bse,ef->bsf", xc, lp["w_bcdt"]).astype(jnp.float32)
     dt_in, Bmat, Cmat = jnp.split(bcdt, [dr, dr + ds], axis=-1)
     dt = jax.nn.softplus(
@@ -175,8 +128,7 @@ def mamba_mixer(cfg: ModelConfig, lp, x, ctx: ShardCtx, h0=None, conv_state=None
         h_all = a * h0[:, None] + bx
         h_end = h_all[:, -1]
     else:
-        h_all, h_end = _ssm_scan_chunked(a, bx, h0, cfg.ssm_scan_chunk,
-                                         unroll=cfg.unroll_scans)
+        h_all, h_end = _ssm_scan_chunked(a, bx, h0, cfg.ssm_scan_chunk)
 
     y = (h_all * Cmat[:, :, None, :]).sum(-1)  # [B, S, di]
     y = y + lp["d_skip"] * xc.astype(jnp.float32)
@@ -200,13 +152,13 @@ def loss_fn(cfg: ModelConfig, params, batch, ctx: ShardCtx = LOCAL_CTX):
         return _mamba_block(cfg, lp, x, ctx), None
 
     body = jax.checkpoint(block) if cfg.remat else block
-    x, _ = jax.lax.scan(body, x, params["blocks"], unroll=True if cfg.unroll_scans else 1)
+    x, _ = jax.lax.scan(body, x, params["blocks"])
     x = rms_norm(x, params["final_norm"])
     B, S, D = x.shape
     return chunked_cross_entropy(
         x.reshape(B * S, D), _unembed_weight(cfg, params),
         batch["labels"].reshape(B * S), chunk=min(cfg.xent_chunk, B * S),
-        rules=ctx.rules, unroll=cfg.unroll_scans,
+        rules=ctx.rules,
     )
 
 
@@ -229,9 +181,7 @@ def decode_step(cfg: ModelConfig, params, state, token, pos, ctx: ShardCtx = LOC
         return x + out, (h2, conv2)
 
     x, new_states = jax.lax.scan(
-        block, x, (params["blocks"], (state["h"], state["conv"])),
-        unroll=True if cfg.unroll_scans else 1,
-    )
+        block, x, (params["blocks"], (state["h"], state["conv"])))
     x = rms_norm(x, params["final_norm"])
     logits = jnp.einsum("bsd,dv->bsv", x, _unembed_weight(cfg, params))
     logits = lc(logits, ("batch", None, "vocab"), ctx.rules)
@@ -244,7 +194,7 @@ def prefill(cfg: ModelConfig, params, tokens, ctx: ShardCtx = LOCAL_CTX):
     def block(x, lp):
         return _mamba_block(cfg, lp, x, ctx), None
 
-    x, _ = jax.lax.scan(block, x, params["blocks"], unroll=True if cfg.unroll_scans else 1)
+    x, _ = jax.lax.scan(block, x, params["blocks"])
     x = rms_norm(x, params["final_norm"])
     logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed_weight(cfg, params))
     return lc(logits, ("batch", "vocab"), ctx.rules)

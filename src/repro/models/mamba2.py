@@ -155,7 +155,7 @@ def mixer(cfg: ModelConfig, lp, x, ctx, state: Optional[Dict] = None):
         dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"])
         A = -jnp.exp(lp["a_log"])
         if state is None:
-            y = ssd(xs, dt, A, Bm, Cm, cfg.ssm_chunk, unroll=cfg.unroll_scans)
+            y = ssd(xs, dt, A, Bm, Cm, cfg.ssm_chunk)
         else:
             R = H // cfg.ssm_groups
             Bh = jnp.repeat(Bm[:, 0], R, axis=1)  # [B, H, N]

@@ -140,13 +140,9 @@ def _project_qkv(cfg: ModelConfig, lp, x, positions, theta, ctx: ShardCtx):
     if cfg.use_rope:
         q = rope(q, positions, theta)
         k = rope(k, positions, theta)
-    if cfg.attn_mode == "seq_tp":
-        # kv must be full-sequence (replicated) for the kv-chunk scan
-        k = lc(k, ("batch", None, "kv_heads", None), ctx.rules)
-        v = lc(v, ("batch", None, "kv_heads", None), ctx.rules)
-    else:
-        k = lc(k, ("batch", None, "kv_heads", None), ctx.rules)
-        v = lc(v, ("batch", None, "kv_heads", None), ctx.rules)
+    # kv whole along the sequence: seq_tp's kv-chunk scan reads all of it
+    k = lc(k, ("batch", None, "kv_heads", None), ctx.rules)
+    v = lc(v, ("batch", None, "kv_heads", None), ctx.rules)
     return q, k, v
 
 
@@ -159,8 +155,7 @@ def _attention_block(cfg: ModelConfig, lp, x, *, layer_global: bool,
     h = rms_norm(x, lp["ln1"], eps=cfg.norm_eps, plus_one=cfg.rms_plus_one)
     q, k, v = _project_qkv(cfg, lp, h, positions, theta, ctx)
     kw = dict(causal=True, window=window, prefix=prefix, q_offset=q_offset,
-              rules=ctx.rules, scale=cfg.attn_logit_scale,
-              unroll=cfg.unroll_scans, probs_bf16=cfg.attn_probs_bf16)
+              rules=ctx.rules, scale=cfg.attn_logit_scale)
     if cfg.attn_mode == "seq_tp":
         o = attention_seq_tp(q, k, v, kv_chunk=cfg.kv_chunk, **kw)
     else:
@@ -191,16 +186,6 @@ def _moe_block_fn(cfg: ModelConfig, ctx: ShardCtx):
         B, S, D = x.shape
         x2d = x.reshape(B * S, D)
         e_loc = E // ep_size
-        if cfg.moe_local_dispatch and ep_size > 1:
-            # §Perf lever: only materialize the local expert range's buffer
-            xe, meta, C = moe_dispatch(
-                x2d, router, n_experts=E, top_k=cfg.top_k, capacity_factor=cf,
-                renormalize=cfg.router_renormalize,
-                expert_lo=ep_rank * e_loc, n_local=e_loc,
-            )
-            out_e = moe_expert_compute(xe, w_in, w_gate, w_out, cfg.act)
-            y = moe_combine(out_e, meta, B * S, D, e_loc, C, x.dtype)
-            return y.reshape(B, S, D)
         xe_all, meta, C = moe_dispatch(
             x2d, router, n_experts=E, top_k=cfg.top_k, capacity_factor=cf,
             renormalize=cfg.router_renormalize,
@@ -219,12 +204,9 @@ def _moe_block_fn(cfg: ModelConfig, ctx: ShardCtx):
         )
 
     mesh, tp = ctx.mesh, ctx.tp_axis
-    ep_size = 1 if cfg.moe_replicate_experts else int(mesh.shape[tp])
+    ep_size = int(mesh.shape[tp])
 
     def shmap_fn(x, router, w_in, w_gate, w_out):
-        if cfg.moe_replicate_experts:
-            # §Perf lever: experts replicated -> no EP psum at all
-            return compute(x, router, w_in, w_gate, w_out, 0, 1)
         ep_rank = jax.lax.axis_index(tp)
         y = compute(x, router, w_in, w_gate, w_out, ep_rank, ep_size)
         return jax.lax.psum(y, tp)
@@ -241,7 +223,7 @@ def _moe_block_fn(cfg: ModelConfig, ctx: ShardCtx):
         else:
             dp_spec = None
         x_spec = P(dp_spec, None, None)
-        w_spec = P(None, None, None) if cfg.moe_replicate_experts else P(tp, None, None)
+        w_spec = P(tp, None, None)
         in_specs = [x_spec, P(None, None), w_spec]
         if cfg.gated_mlp:
             in_specs.append(w_spec)
@@ -282,12 +264,7 @@ def _layer(cfg: ModelConfig, lp, x, *, layer_global: bool, is_moe: bool,
 
 
 def _maybe_remat(cfg: ModelConfig, fn):
-    if not cfg.remat:
-        return fn
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    return jax.checkpoint(fn)
+    return jax.checkpoint(fn) if cfg.remat else fn
 
 
 # --------------------------------------------------------------------------
@@ -319,14 +296,12 @@ def _backbone(cfg: ModelConfig, params, x, ctx: ShardCtx,
                            ctx=ctx, q_offset=q_offset)
             return x, None
 
-        x, _ = jax.lax.scan(_maybe_remat(cfg, super_block), x, params["blocks"],
-                            unroll=True if cfg.unroll_scans else 1)
+        x, _ = jax.lax.scan(_maybe_remat(cfg, super_block), x, params["blocks"])
         if "tail" in params:
             def tail_block(x, lp):
                 return _layer(cfg, lp, x, layer_global=False, is_moe=False,
                               prefix=prefix, ctx=ctx, q_offset=q_offset), None
-            x, _ = jax.lax.scan(_maybe_remat(cfg, tail_block), x, params["tail"],
-                                unroll=True if cfg.unroll_scans else 1)
+            x, _ = jax.lax.scan(_maybe_remat(cfg, tail_block), x, params["tail"])
         return x
 
     is_moe = cfg.n_experts > 0 and cfg.moe_period == 1
@@ -335,8 +310,7 @@ def _backbone(cfg: ModelConfig, params, x, ctx: ShardCtx,
         return _layer(cfg, lp, x, layer_global=True, is_moe=is_moe,
                       prefix=prefix, ctx=ctx, q_offset=q_offset), None
 
-    x, _ = jax.lax.scan(_maybe_remat(cfg, block), x, params["blocks"],
-                        unroll=True if cfg.unroll_scans else 1)
+    x, _ = jax.lax.scan(_maybe_remat(cfg, block), x, params["blocks"])
     return x
 
 
@@ -362,7 +336,6 @@ def loss_fn(cfg: ModelConfig, params, batch, ctx: ShardCtx = LOCAL_CTX):
     return chunked_cross_entropy(
         x.reshape(B * S, D), _unembed_weight(cfg, params), labels,
         chunk=min(cfg.xent_chunk, B * S), rules=ctx.rules,
-        unroll=cfg.unroll_scans,
     )
 
 
@@ -437,8 +410,7 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos, ctx: ShardCtx = LOC
                     layer_global=is_glob, is_moe=False, ctx=ctx)
             return x, new_c
 
-        x, new_blocks = jax.lax.scan(super_block, x, (params["blocks"], cache["blocks"]),
-                                     unroll=True if cfg.unroll_scans else 1)
+        x, new_blocks = jax.lax.scan(super_block, x, (params["blocks"], cache["blocks"]))
         new_cache = {"blocks": new_blocks}
         if "tail" in params:
             def tail_block(x, lc_):
@@ -446,8 +418,7 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos, ctx: ShardCtx = LOC
                 x, nc = _decode_layer(cfg, lp, cch, x, pos, layer_global=False,
                                       is_moe=False, ctx=ctx)
                 return x, nc
-            x, new_tail = jax.lax.scan(tail_block, x, (params["tail"], cache["tail"]),
-                                       unroll=True if cfg.unroll_scans else 1)
+            x, new_tail = jax.lax.scan(tail_block, x, (params["tail"], cache["tail"]))
             new_cache["tail"] = new_tail
     else:
         is_moe = cfg.n_experts > 0 and cfg.moe_period == 1
@@ -457,8 +428,7 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos, ctx: ShardCtx = LOC
             return _decode_layer(cfg, lp, cch, x, pos, layer_global=True,
                                  is_moe=is_moe, ctx=ctx)
 
-        x, new_blocks = jax.lax.scan(block, x, (params["blocks"], cache["blocks"]),
-                                     unroll=True if cfg.unroll_scans else 1)
+        x, new_blocks = jax.lax.scan(block, x, (params["blocks"], cache["blocks"]))
         new_cache = {"blocks": new_blocks}
 
     x = rms_norm(x, params["final_norm"], plus_one=cfg.rms_plus_one)

@@ -87,8 +87,7 @@ def _self_attention(cfg, lp, x, causal, ctx, name="attn", kv_x=None):
     v = jnp.einsum("bsd,dhk->bshk", h, lp[name]["wv"])
     k = lc(k, ("batch", None, "kv_heads", None), ctx.rules)
     v = lc(v, ("batch", None, "kv_heads", None), ctx.rules)
-    o = attention_seq_tp(q, k, v, causal=causal, kv_chunk=cfg.kv_chunk,
-                         rules=ctx.rules, unroll=cfg.unroll_scans)
+    o = attention_seq_tp(q, k, v, causal=causal, kv_chunk=cfg.kv_chunk, rules=ctx.rules)
     return jnp.einsum("bshk,hkd->bsd", o, lp[name]["wo"])
 
 
@@ -111,7 +110,7 @@ def encode(cfg: ModelConfig, params, frames, ctx: ShardCtx = LOCAL_CTX):
         return _enc_layer(cfg, lp, x, ctx), None
 
     body = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body, x, params["enc"], unroll=True if cfg.unroll_scans else 1)
+    x, _ = jax.lax.scan(body, x, params["enc"])
     return layer_norm(x, params["enc_final"]["scale"], params["enc_final"]["bias"])
 
 
@@ -136,7 +135,7 @@ def decode_train(cfg: ModelConfig, params, tokens, enc_states, ctx: ShardCtx = L
         return _dec_layer(cfg, lp, x, enc_states, ctx), None
 
     body = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body, x, params["dec"], unroll=True if cfg.unroll_scans else 1)
+    x, _ = jax.lax.scan(body, x, params["dec"])
     return layer_norm(x, params["dec_final"]["scale"], params["dec_final"]["bias"])
 
 
@@ -148,7 +147,7 @@ def loss_fn(cfg: ModelConfig, params, batch, ctx: ShardCtx = LOCAL_CTX):
     return chunked_cross_entropy(
         x.reshape(B * S, D), params["embed"].astype(cfg.dtype).T,
         batch["labels"].reshape(B * S), chunk=min(cfg.xent_chunk, B * S),
-        rules=ctx.rules, unroll=cfg.unroll_scans,
+        rules=ctx.rules,
     )
 
 
@@ -207,9 +206,7 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos, ctx: ShardCtx = LOC
 
     x, new_cache = jax.lax.scan(
         body, x,
-        (params["dec"], {k: cache[k] for k in ("self_k", "self_v", "cross_k", "cross_v")}),
-        unroll=True if cfg.unroll_scans else 1,
-    )
+        (params["dec"], {k: cache[k] for k in ("self_k", "self_v", "cross_k", "cross_v")}))
     x = layer_norm(x, params["dec_final"]["scale"], params["dec_final"]["bias"])
     logits = jnp.einsum("bsd,dv->bsv", x, params["embed"].astype(cfg.dtype).T)
     return lc(logits[:, 0], ("batch", "vocab"), ctx.rules), new_cache

@@ -1,5 +1,4 @@
-"""repro.optim — AdamW, schedules, clipping, gradient compression."""
+"""repro.optim — AdamW, schedules, clipping."""
 
 from .adamw import AdamWConfig, adamw_init_specs, adamw_update  # noqa: F401
 from .schedule import cosine_schedule  # noqa: F401
-from .compress import compress_grads, decompress_grads  # noqa: F401

@@ -1,7 +1,6 @@
 """Per-(arch × shape × mesh) sharding-rule selection.
 
-This is the baseline policy; hillclimbing (EXPERIMENTS.md §Perf) perturbs the
-returned Rules. Policy:
+Policy:
 
 - batch   -> all DP axes ("pod","data") when the global batch divides; else None
 - heads / kv_heads -> "model" when divisible by TP (heads_tp archs)

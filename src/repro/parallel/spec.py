@@ -2,8 +2,8 @@
 
 Every parameter/activation declares *logical* axes ("vocab", "embed", "mlp",
 "heads", "expert", "batch", "seq", ...). A ``Rules`` mapping assigns logical
-axes to mesh axes; changing the mapping re-shards the whole model — this is
-the main hillclimbing lever, no model code changes needed.
+axes to mesh axes; changing the mapping re-shards the whole model with no
+model code changes.
 """
 
 from __future__ import annotations
@@ -71,7 +71,7 @@ DEFAULT_RULES = Rules.make(
     embed=None,
     seq=None,
     kv_seq="model",     # decode KV-cache sequence sharding (MQA/GQA fallback)
-    act_seq=None,       # activation sequence dim (SP hillclimb lever)
+    act_seq=None,       # activation sequence dim (sequence parallelism)
     state=None,
     layers=None,
     conv=None,

@@ -1,4 +1,4 @@
 """repro.train — step builders and the fault-tolerant trainer."""
 
-from .step import StepBundle, make_prefill_bundle, make_serve_bundle, make_train_bundle  # noqa: F401
+from .step import StepBundle, make_serve_bundle, make_train_bundle  # noqa: F401
 from .trainer import Trainer, TrainerConfig  # noqa: F401

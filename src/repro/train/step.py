@@ -18,7 +18,7 @@ from ..optim import AdamWConfig, adamw_init_specs, adamw_update, cosine_schedule
 from ..parallel.rules import make_rules, mesh_dp_axes
 from ..parallel.spec import Rules, abstract_params, partition_spec, tree_partition_specs
 
-__all__ = ["StepBundle", "make_train_bundle", "make_serve_bundle", "make_prefill_bundle"]
+__all__ = ["StepBundle", "make_train_bundle", "make_serve_bundle"]
 
 
 @dataclasses.dataclass
@@ -101,10 +101,7 @@ def make_train_bundle(
             gsum = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), gsum, grads)
             return (lsum + loss, gsum), None
 
-        (lsum, gsum), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), zeros), micro,
-            unroll=True if cfg.unroll_scans else 1,
-        )
+        (lsum, gsum), _ = jax.lax.scan(body, (jnp.zeros((), jnp.float32), zeros), micro)
         inv = 1.0 / accum_steps
         return lsum * inv, jax.tree.map(lambda g: g * inv, gsum)
 
@@ -172,44 +169,3 @@ def make_serve_bundle(
         ctx=ctx,
     )
 
-
-def make_prefill_bundle(
-    cfg: ModelConfig, shape, mesh=None, multi_pod: bool = False,
-    rules: Optional[Rules] = None,
-) -> StepBundle:
-    from ..configs import input_specs
-
-    api = get_api(cfg)
-    rules = rules or make_rules(cfg, "prefill", shape.global_batch, multi_pod)
-    ctx = ShardCtx(mesh=mesh, rules=rules, dp_axes=mesh_dp_axes(multi_pod))
-    spec = input_specs(cfg, shape)
-    pspecs = api.param_specs(cfg)
-    param_pspec = tree_partition_specs(pspecs, rules)
-    inputs = spec["inputs"]
-    in_pspec = _batch_pspec(inputs, rules)
-    logits_pspec = partition_spec(("batch", "vocab"), rules)
-
-    if cfg.family == "encdec":
-        def prefill_fn(params, frames):
-            return api.prefill(cfg, params, frames, ctx)
-        abstract = (abstract_params(pspecs), inputs["frames"])
-        in_sh = (param_pspec, in_pspec["frames"])
-    elif cfg.family == "vlm":
-        def prefill_fn(params, prefix_embeds, tokens):
-            from ..models import transformer
-            return transformer.prefill(cfg, params, tokens, ctx, prefix_embeds=prefix_embeds)
-        abstract = (abstract_params(pspecs), inputs["prefix_embeds"], inputs["tokens"])
-        in_sh = (param_pspec, in_pspec["prefix_embeds"], in_pspec["tokens"])
-    else:
-        def prefill_fn(params, tokens):
-            return api.prefill(cfg, params, tokens, ctx)
-        abstract = (abstract_params(pspecs), inputs["tokens"])
-        in_sh = (param_pspec, in_pspec["tokens"])
-
-    return StepBundle(
-        fn=prefill_fn,
-        in_shardings=in_sh,
-        out_shardings=logits_pspec,
-        abstract_inputs=abstract,
-        ctx=ctx,
-    )

@@ -80,7 +80,7 @@ class Trainer:
         self.ckpt = CheckpointManager(tcfg.ckpt_dir, keep=tcfg.ckpt_keep)
         self._stop = False
 
-        # jit'd step (local mesh-free path; launch/train.py builds the pjit one)
+        # jit'd step (mesh-free; train.step.make_train_bundle builds a meshed one)
         def step_fn(state, batch):
             def loss_of(p):
                 return self.api.loss_fn(cfg, p, batch)

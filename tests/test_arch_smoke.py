@@ -126,6 +126,26 @@ def test_moe_capacity_drops_are_bounded(arch_state):
     assert xe.shape == (cfg.n_experts_padded, C, cfg.d_model)
 
 
+def test_remat_keeps_loss_and_gradients():
+    """``cfg.remat`` wraps each layer in ``jax.checkpoint``, which recomputes
+    the forward in the backward: the loss and every gradient stay those of
+    the step without it (float32, so the two agree to its rounding)."""
+    cfg = reduced(get_config("granite-moe-1b-a400m")).replace(dtype=jnp.float32)
+    api = get_api(cfg)
+    params = init_params(api.param_specs(cfg), jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + 1), 0, cfg.vocab_size)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+
+    def loss_and_grads(c):
+        return jax.jit(jax.value_and_grad(lambda p: api.loss_fn(c, p, batch)))(params)
+
+    l0, g0 = loss_and_grads(cfg.replace(remat=False))
+    l1, g1 = loss_and_grads(cfg.replace(remat=True))
+    assert float(l1) == pytest.approx(float(l0), rel=1e-6)
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-5, atol=1e-7)
+
+
 def test_long_context_support_flags():
     from repro.configs import shape_supported
 

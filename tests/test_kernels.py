@@ -242,58 +242,18 @@ def test_mamba_scan_kernel_sweep(b, n_chunks, di, ds, seed):
     assert float(jnp.max(jnp.abs(y_k - y_r))) < 1e-4
 
 
-def test_ssm_chunk_local_path_matches_reference():
-    """§Perf T1 lever correctness: chunk-local gates == full-seq reference."""
-    import numpy as np
+@pytest.mark.parametrize("mask", [dict(causal=True), dict(causal=True, window=24)],
+                         ids=["causal", "window"])
+def test_attention_seq_tp_matches_heads_tp(mask):
+    """The two attention modes ``configs/archs.py`` picks between agree: the
+    kv-chunk scan of ``seq_tp`` against the query-chunk scan of
+    ``heads_tp`` (head size 16, which the flash kernel does not take), with
+    four query heads to a kv head and several chunks of each."""
+    from repro.models.common import attention_heads_tp, attention_seq_tp
 
-    from repro.configs import get_config, reduced
-    from repro.models import get_api
-    from repro.parallel.spec import init_params
-
-    cfg0 = reduced(get_config("falcon-mamba-7b")).replace(ssm_scan_chunk=8)
-    cfg1 = cfg0.replace(ssm_chunk_local=True)
-    api = get_api(cfg0)
-    params = init_params(api.param_specs(cfg0), jax.random.PRNGKey(0))
-    batch = {"tokens": jnp.arange(2 * 32).reshape(2, 32) % cfg0.vocab_size,
-             "labels": jnp.ones((2, 32), jnp.int32)}
-    l0 = float(api.loss_fn(cfg0, params, batch))
-    l1 = float(api.loss_fn(cfg1, params, batch))
-    assert abs(l0 - l1) < 1e-5
-
-
-def test_moe_local_dispatch_matches_full():
-    """§Perf T3 lever correctness: sharded local dispatch sums == full."""
-    import numpy as np
-
-    from repro.models.common import moe_combine, moe_dispatch, moe_expert_compute
-
-    T, D, E, K = 64, 16, 8, 2
-    x = jax.random.normal(jax.random.PRNGKey(1), (T, D), jnp.float32)
-    router = jax.random.normal(jax.random.PRNGKey(2), (D, E), jnp.float32)
-    w_in = jax.random.normal(jax.random.PRNGKey(3), (E, D, 32), jnp.float32) * 0.1
-    w_gate = jax.random.normal(jax.random.PRNGKey(4), (E, D, 32), jnp.float32) * 0.1
-    w_out = jax.random.normal(jax.random.PRNGKey(5), (E, 32, D), jnp.float32) * 0.1
-    xe, meta, C = moe_dispatch(x, router, n_experts=E, top_k=K, capacity_factor=1.25)
-    full = moe_combine(moe_expert_compute(xe, w_in, w_gate, w_out), meta, T, D, E, C,
-                       jnp.float32)
-    acc = jnp.zeros((T, D), jnp.float32)
-    for rank in range(2):
-        lo, nl = rank * 4, 4
-        xe_l, meta_l, C2 = moe_dispatch(
-            x, router, n_experts=E, top_k=K, capacity_factor=1.25,
-            expert_lo=lo, n_local=nl)
-        acc = acc + moe_combine(
-            moe_expert_compute(xe_l, w_in[lo:lo + nl], w_gate[lo:lo + nl],
-                               w_out[lo:lo + nl]),
-            meta_l, T, D, nl, C2, jnp.float32)
-    assert float(jnp.max(jnp.abs(acc - full))) < 1e-5
-
-
-def test_attn_probs_bf16_close_to_f32():
-    """§Perf T2 lever: bf16 probs stay within bf16 tolerance of f32 path."""
-    from repro.models.common import attention_heads_tp
-
-    q, k, v = _qkv(jax.random.PRNGKey(7), 2, 128, 8, 4, 64, jnp.float32)
-    o32 = attention_heads_tp(q, k, v, q_chunk=64)
-    o16 = attention_heads_tp(q, k, v, q_chunk=64, probs_bf16=True)
-    assert float(jnp.max(jnp.abs(o32 - o16))) < 3e-2
+    q, k, v = _qkv(jax.random.PRNGKey(9), 2, 64, 8, 2, 16, jnp.float32)
+    o_heads = attention_heads_tp(q, k, v, q_chunk=16, **mask)
+    o_seq = attention_seq_tp(q, k, v, kv_chunk=32, **mask)
+    r = attention_reference(q, k, v, **mask)
+    assert float(jnp.max(jnp.abs(o_seq - o_heads))) < TOL[jnp.float32]
+    assert float(jnp.max(jnp.abs(o_heads - r))) < TOL[jnp.float32]

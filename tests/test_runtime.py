@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager
-from repro.optim import AdamWConfig, adamw_update, compress_grads, cosine_schedule, decompress_grads
+from repro.optim import AdamWConfig, adamw_update, cosine_schedule
 
 
 # ---------------------------------------------------------------- optim
@@ -39,19 +39,6 @@ def test_cosine_schedule_shape():
     sw = float(cosine_schedule(jnp.int32(10), warmup=10, total=100))
     send = float(cosine_schedule(jnp.int32(100), warmup=10, total=100))
     assert s0 == 0.0 and abs(sw - 1.0) < 1e-6 and send == pytest.approx(0.1, abs=1e-6)
-
-
-def test_gradient_compression_error_feedback():
-    rng = np.random.default_rng(0)
-    g = {"w": jnp.asarray(rng.normal(size=512).astype(np.float32))}
-    q, s, resid = compress_grads(g)
-    deq = decompress_grads(q, s)
-    err = float(jnp.abs(deq["w"] - g["w"]).max())
-    assert err <= float(s["w"]) * 0.5 + 1e-7  # quantization bound
-    # error feedback: residual carries exactly the quantization error
-    np.testing.assert_allclose(
-        np.asarray(resid["w"]), np.asarray(g["w"] - deq["w"]), atol=1e-7
-    )
 
 
 # ---------------------------------------------------------------- checkpoint

@@ -148,3 +148,49 @@ def test_small_multipod_mesh_train_compiles():
 def test_small_mesh_decode_compiles():
     out = _run_mesh("falcon-mamba-7b", "decode_32k", data=2, model=4)
     assert out["ok"]
+
+
+_EP_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax
+import jax.numpy as jnp
+from repro.configs import get_config, reduced
+from repro.launch.mesh import make_test_mesh
+from repro.models.transformer import LOCAL_CTX, ShardCtx, _moe_block_fn
+
+# 16 experts, so that each of the 4 ranks holds 4 that receive tokens
+cfg = reduced(get_config("granite-moe-1b-a400m")).replace(
+    n_experts=16, top_k=4, dtype=jnp.float32)
+E, D, F = cfg.n_experts_padded, cfg.d_model, cfg.d_ff
+ks = jax.random.split(jax.random.PRNGKey(0), 5)
+x = jax.random.normal(ks[0], (2, 32, D), jnp.float32)
+mp = {"router": jax.random.normal(ks[1], (D, cfg.n_experts), jnp.float32),
+      "w_in": jax.random.normal(ks[2], (E, D, F), jnp.float32) * D ** -0.5,
+      "w_gate": jax.random.normal(ks[3], (E, D, F), jnp.float32) * D ** -0.5,
+      "w_out": jax.random.normal(ks[4], (E, F, D), jnp.float32) * F ** -0.5}
+y_ref = jax.jit(_moe_block_fn(cfg, LOCAL_CTX))(x, mp)
+mesh = make_test_mesh(data=1, model=4)
+with mesh:
+    ep = jax.jit(_moe_block_fn(cfg, ShardCtx(mesh=mesh)))
+    hlo = ep.lower(x, mp).compile().as_text()
+    y_ep = ep(x, mp)
+print(json.dumps({"err": float(jnp.max(jnp.abs(y_ep - y_ref))),
+                  "scale": float(jnp.max(jnp.abs(y_ref))),
+                  "all_reduce": "all-reduce" in hlo}))
+"""
+
+
+def test_expert_parallel_moe_matches_unmeshed():
+    """The MoE layer under a mesh (``shard_map`` over 4 expert-parallel
+    ranks, each computing its slice of experts, combined by a ``psum``)
+    gives the unmeshed layer's output to float32 rounding."""
+    env = dict(os.environ, PYTHONPATH="src")
+    r = subprocess.run([sys.executable, "-c", _EP_SCRIPT], capture_output=True,
+                       text=True, cwd=_REPO, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["all_reduce"]
+    assert out["scale"] > 0.1
+    assert out["err"] < 1e-5 * out["scale"], out
